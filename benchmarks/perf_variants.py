@@ -16,7 +16,7 @@ from benchmarks.common import ARTIFACTS, table
 
 
 def main():
-    # the 512-device override must precede jax init (dryrun does it on import)
+    # lower_cell asks for the 512 host devices before jax first initializes
     from repro.launch.dryrun import lower_cell
     from repro.parallel.strategies import get_strategy
 

@@ -20,6 +20,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 QUICK_BASELINE = os.path.join(os.path.dirname(__file__), "BENCH_quick.json")
 QUICK_LATEST = os.path.join(os.path.dirname(__file__), "BENCH_quick.latest.json")
 
@@ -70,6 +72,14 @@ SWEEP_BATCHED_SPEC = dict(
     scenarios=("paper-table6", "forecastable-brownouts"),
     policies=("feasibility-aware",), seeds=tuple(range(500)),
     overrides=dict(n_jobs=6, days=1, orch_dt_s=1800.0))
+
+
+#: 8-run process-pool mini-sweep (2 scenarios x 2 policies x 2 seeds of
+#: 3-day, 80-job cells): the pool fan-out end to end.
+MINI_SWEEP_SPEC = dict(
+    scenarios=("paper-table6", "forecastable-brownouts"),
+    policies=("feasibility-aware", "plan-ahead"), seeds=(0, 1),
+    overrides=dict(days=3, n_jobs=80))
 
 
 def quick_smoke(json_path: str = QUICK_LATEST) -> int:
@@ -256,11 +266,8 @@ def quick_smoke(json_path: str = QUICK_LATEST) -> int:
     }
     ok &= same_serving and ch_r.requests_served > 0
     # mini-sweep: exercises the process-pool fan-out end to end in CI
-    spec = SweepSpec(
-        scenarios=("paper-table6", "forecastable-brownouts"),
-        policies=("feasibility-aware", "plan-ahead"), seeds=(0, 1),
-        overrides=dict(days=3, n_jobs=80))
-    sw = run_sweep(spec, workers=2, keep_results=False)
+    sw = run_sweep(SweepSpec(**MINI_SWEEP_SPEC), workers=2,
+                   keep_results=False)
     completed = sum(r.summary["completed"] for r in sw.runs)
     # the gated quantity is the summed in-simulator wall, not the pool
     # wall: process spawn/import overhead tracks runner provisioning, not
@@ -408,6 +415,7 @@ def main() -> None:
                     default=os.path.join(os.path.dirname(__file__),
                                          "PROFILE_top15.csv"))
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.quick:
         sys.exit(quick_smoke(args.quick_json))

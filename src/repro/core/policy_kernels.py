@@ -18,9 +18,9 @@ three ways:
   per tick (``pad_jobs`` / ``pad_sites``);
 * **compilation** — the same fused math is available as one
   ``jax.jit``-compiled XLA program and as a pallas kernel following the
-  repo's ``kernels/flash_attention.py`` idiom (VMEM-tiled over the sites
-  axis, masked padding lanes, running lexicographic argbest across site
-  tiles).
+  repo's ``kernels/flash_attention.py`` idiom (jobs on the 128-wide lane
+  axis, sites on sublanes, VMEM-tiled over both, masked padding lanes,
+  running lexicographic argbest across site tiles).
 
 Backend selection (:func:`backend` / :func:`set_backend`):
 
@@ -30,9 +30,9 @@ Backend selection (:func:`backend` / :func:`set_backend`):
   ``decide_scalar`` oracles; every gated benchmark digit is produced by
   this backend.
 * ``jit`` — the fused kernel as one jitted XLA call in float64
-  (``jax.experimental.enable_x64``): same math, one dispatch.
-* ``pallas`` — the tiled kernel (float32 accumulation, ``interpret=True``
-  off-TPU); auto-selected on TPU.
+  (``jax.enable_x64``): same math, one dispatch.
+* ``pallas`` — the tiled kernel (float32 compares on a float64
+  ``t_transfer``, ``interpret=True`` off-TPU); auto-selected on TPU.
 
 The ``REPRO_DECIDE_BACKEND`` environment variable overrides the default.
 Compiled backends return only the argbest destination per row; the rare
@@ -99,14 +99,9 @@ def backend() -> str:
                     f"not {env!r}")
             _backend = env
         else:
-            _backend = "numpy"
-            try:
-                import jax
+            import jax
 
-                if jax.default_backend() == "tpu":
-                    _backend = "pallas"
-            except Exception:  # pragma: no cover - jax always importable here
-                pass
+            _backend = "pallas" if jax.default_backend() == "tpu" else "numpy"
     return _backend
 
 
@@ -205,7 +200,7 @@ def pad_jobs(k: int) -> int:
 
 def pad_sites(n: int) -> int:
     """Site-axis padding bucket: next multiple of 8 (the pallas wrapper
-    re-pads to its 128-lane tile internally)."""
+    re-pads to a whole number of site tiles internally)."""
     return ((n + 7) // 8) * 8
 
 
@@ -488,7 +483,7 @@ def _score_jit(batch: ScoreBatch, params: ScoreParams) -> np.ndarray:
     do)."""
     import jax
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = _jit_fn()(
             batch.sizes, batch.t_loads, batch.rem, batch.cur_green,
             batch.load_src, batch.s_i, batch.bw, batch.W, batch.bq_load,
@@ -500,29 +495,31 @@ def _score_jit(batch: ScoreBatch, params: ScoreParams) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# pallas backend — VMEM-tiled over the sites axis (flash_attention idiom)
+# pallas backend — jobs on lanes, sites on sublanes, tiled over both
 # ---------------------------------------------------------------------------
 
 NEG_INF = -2.0e38  # large-but-finite f32 sentinels (flash_attention idiom)
 POS_INF = 2.0e38
 BIG_IDX = 2 ** 30
 
-_BLOCK_J = 8
-_BLOCK_S = 128
+_BLOCK_J = 512  # job lanes per tile (a multiple of 128, or all of K)
+_BLOCK_S = 128  # site sublanes per tile (a multiple of 8, or all of S)
 
 
-def _dest_kernel(sizes_ref, t_loads_ref, rem_ref, cur_green_ref,
-                 load_src_ref, s_i_ref, bw_ref, W_ref, bq_load_ref,
-                 free_pen_ref, dest_ref, mb_scr, mtt_scr, mdest_scr, *,
-                 alpha, gamma, betaqp, min_benefit_s, ppf_sigma, use_stoch,
-                 block_j, block_s, n_s_blocks):
-    """One (batch, job-tile, site-tile) grid step: score the tile, fold
-    it into the running lexicographic argbest held in VMEM scratch, and
-    emit destinations after the last site tile.
+def _dest_kernel(tt_ref, t_loads_ref, rem_ref, cur_green_ref, load_src_ref,
+                 s_i_ref, W_ref, bq_load_ref, free_pen_ref, dest_ref,
+                 mb_scr, mtt_scr, mdest_scr, *, alpha, gamma, betaqp,
+                 min_benefit_s, ppf_sigma, use_stoch, block_s, n_s_blocks):
+    """One (batch, job-tile, site-tile) grid step over a ``(bs, bj)``
+    tile — sites on sublanes, jobs on lanes: score the tile, fold it into
+    the running lexicographic argbest held in VMEM scratch, and emit
+    destinations after the last site tile.
 
+    Job columns arrive as ``(1, bj)`` rows and site columns as ``(bs, 1)``
+    columns, so every operand broadcasts to the tile without a relayout.
     The cross-tile update keeps the *earlier* tile on exact
     (benefit, t_transfer) ties, and the within-tile reduction takes the
-    lowest sid among tied lanes — together reproducing numpy argmax's
+    lowest sid among tied sublanes — together reproducing numpy argmax's
     first-occurrence (lowest-sid) rule globally.
     """
     import jax
@@ -533,15 +530,13 @@ def _dest_kernel(sizes_ref, t_loads_ref, rem_ref, cur_green_ref,
 
     @pl.when(si == 0)
     def _init():
-        mb_scr[...] = jnp.full((block_j,), NEG_INF, jnp.float32)
-        mtt_scr[...] = jnp.full((block_j,), POS_INF, jnp.float32)
-        mdest_scr[...] = jnp.full((block_j,), -1, jnp.int32)
+        mb_scr[...] = jnp.full(mb_scr.shape, NEG_INF, jnp.float32)
+        mtt_scr[...] = jnp.full(mtt_scr.shape, POS_INF, jnp.float32)
+        mdest_scr[...] = jnp.full(mdest_scr.shape, -1, jnp.int32)
 
-    sizes = sizes_ref[0, :]          # (bj,)
-    bw = bw_ref[0, :, :]             # (bj, bs)
-    W = W_ref[0, :][None, :]         # (1, bs)
-    tt = 8.0 * sizes[:, None] / bw   # 0-bandwidth lanes -> inf -> infeasible
-    t_cost = tt + t_loads_ref[0, :][:, None] + fz.T_DOWNTIME_S
+    tt = tt_ref[...]                 # (bs, bj); +inf on dead/padded lanes
+    W = W_ref[...]                   # (bs, 1)
+    t_cost = tt + t_loads_ref[...] + fz.T_DOWNTIME_S
     energy_ok = (fz.P_SYS_KW / fz.P_NODE_KW) * tt < W
     not_c = tt < fz.CLASS_B_MAX_S
     if use_stoch:
@@ -549,26 +544,23 @@ def _dest_kernel(sizes_ref, t_loads_ref, rem_ref, cur_green_ref,
     else:
         time_ok = t_cost < alpha * W
     ok = time_ok & energy_ok & not_c
-    rem = rem_ref[0, :][:, None]
+    rem = rem_ref[...]               # (1, bj)
     avoided = jnp.maximum(
-        0.0, jnp.minimum(W, rem)
-        - jnp.minimum(cur_green_ref[0, :][:, None], rem))
+        0.0, jnp.minimum(W, rem) - jnp.minimum(cur_green_ref[...], rem))
     benefit = (gamma * avoided
-               - betaqp * (bq_load_ref[0, :][None, :]
-                           - load_src_ref[0, :][:, None]))
-    benefit = benefit + free_pen_ref[0, :][None, :]
-    sid = (jax.lax.broadcasted_iota(jnp.int32, (block_j, block_s), 1)
-           + si * block_s)
+               - betaqp * (bq_load_ref[...] - load_src_ref[...]))
+    benefit = benefit + free_pen_ref[...]
+    sid = jax.lax.broadcasted_iota(jnp.int32, tt.shape, 0) + si * block_s
     valid = (ok
-             & (sid != s_i_ref[0, :][:, None])
+             & (sid != s_i_ref[...])
              & (benefit > jnp.maximum(t_cost, min_benefit_s)))
     b = jnp.where(valid, benefit, NEG_INF)
-    mb_tile = b.max(axis=1)
-    tie = valid & (b == mb_tile[:, None])
+    mb_tile = b.max(axis=0, keepdims=True)
+    tie = valid & (b == mb_tile)
     ttm = jnp.where(tie, tt, POS_INF)
-    mtt_tile = ttm.min(axis=1)
-    tie = tie & (ttm == mtt_tile[:, None])
-    dest_tile = jnp.where(tie, sid, BIG_IDX).min(axis=1).astype(jnp.int32)
+    mtt_tile = ttm.min(axis=0, keepdims=True)
+    tie = tie & (ttm == mtt_tile)
+    dest_tile = jnp.where(tie, sid, BIG_IDX).min(axis=0, keepdims=True)
 
     mb_prev = mb_scr[...]
     mtt_prev = mtt_scr[...]
@@ -583,79 +575,113 @@ def _dest_kernel(sizes_ref, t_loads_ref, rem_ref, cur_green_ref,
     @pl.when(si == n_s_blocks - 1)
     def _done():
         # no-valid rows never improved on the init state -> stay -1
-        dest_ref[0, :] = mdest_scr[...]
+        dest_ref[...] = mdest_scr[...]
+
+
+def _blocks(K: int, S: int) -> Tuple[int, int, int]:
+    """``(block_j, block_s, S_pad)`` for a padded batch: each block is
+    either a whole axis or a tile-aligned slice (lanes: multiple of 128,
+    sublanes: multiple of 8), so every padding bucket is a legal TPU
+    tiling.  ``K`` is a power of two (:func:`pad_jobs`) and ``S`` a
+    multiple of 8 (:func:`pad_sites`)."""
+    block_j = min(K, _BLOCK_J)
+    block_s = min(S, _BLOCK_S)
+    return block_j, block_s, -(-S // block_s) * block_s
 
 
 @functools.lru_cache(maxsize=64)
 def _pallas_fn(B: int, K: int, S: int, alpha: float, gamma: float,
                betaqp: float, min_benefit_s: float, ppf_sigma: float,
                use_stoch: bool, interpret: bool):
-    """Build + jit one pallas_call for a padded batch shape (lru-cached
-    so padding buckets, not raw job counts, bound the compile count)."""
+    """Build + jit one pallas_call for a padded ``(B, S, K)`` batch
+    (lru-cached so padding buckets, not raw job counts, bound the
+    compile count)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    block_j, block_s = _BLOCK_J, _BLOCK_S
+    block_j, block_s, S_pad = _blocks(K, S)
+    assert S_pad == S, (S, S_pad)
     n_j, n_s = K // block_j, S // block_s
     kernel = functools.partial(
         _dest_kernel, alpha=alpha, gamma=gamma, betaqp=betaqp,
         min_benefit_s=min_benefit_s, ppf_sigma=ppf_sigma,
-        use_stoch=use_stoch, block_j=block_j, block_s=block_s,
-        n_s_blocks=n_s)
-    job_spec = pl.BlockSpec((1, block_j), lambda b, j, s: (b, j))
-    site_spec = pl.BlockSpec((1, block_s), lambda b, j, s: (b, s))
+        use_stoch=use_stoch, block_s=block_s, n_s_blocks=n_s)
+    # leading batch dim squeezed: kernels see (1, bj) / (bs, 1) / (bs, bj)
+    job_spec = pl.BlockSpec((None, 1, block_j), lambda b, j, s: (b, 0, j))
+    site_spec = pl.BlockSpec((None, block_s, 1), lambda b, j, s: (b, s, 0))
     call = pl.pallas_call(
         kernel,
         grid=(B, n_j, n_s),
-        in_specs=[job_spec, job_spec, job_spec, job_spec, job_spec,
-                  job_spec,
-                  pl.BlockSpec((1, block_j, block_s),
-                               lambda b, j, s: (b, j, s)),
+        in_specs=[pl.BlockSpec((None, block_s, block_j),
+                               lambda b, j, s: (b, s, j)),
+                  job_spec, job_spec, job_spec, job_spec, job_spec,
                   site_spec, site_spec, site_spec],
         out_specs=job_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((block_j,), jnp.float32),
-                        pltpu.VMEM((block_j,), jnp.float32),
-                        pltpu.VMEM((block_j,), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((B, 1, K), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, block_j), jnp.float32),
+                        pltpu.VMEM((1, block_j), jnp.float32),
+                        pltpu.VMEM((1, block_j), jnp.int32)],
         interpret=interpret,
     )
     return jax.jit(call)
 
 
-def _score_pallas(batch: ScoreBatch, params: ScoreParams) -> np.ndarray:
-    """The tiled kernel (float32; ``interpret=True`` off-TPU).  The site
-    axis is re-padded from the 8-bucket to the 128-lane tile — the extra
-    lanes carry the same infeasible padding values."""
-    import jax
-    import jax.numpy as jnp
+def _pallas_inputs(batch: ScoreBatch, params: ScoreParams):
+    """The kernel's build key (:func:`_pallas_fn` minus ``interpret``)
+    and its host operands for one batch.
 
+    ``t_transfer`` is computed here in float64 — the exact expression of
+    :func:`_score_numpy` — and handed to the kernel rounded once to
+    float32, so the kernel itself does no division (the TPU divides by a
+    refined reciprocal) and checkpoint sizes of ~1e10 bytes never need
+    to be exact in float32.  The rest is add / multiply / compare in
+    float32.  The site axis is re-padded to the site tile, and the batch
+    axis to the next power of two (so a sweep's drifting cell count
+    reuses a few compiled shapes), with dead (``t_transfer = inf``)
+    lanes."""
     B, K = batch.sizes.shape
     S = batch.bw.shape[2]
-    S_pad = ((S + _BLOCK_S - 1) // _BLOCK_S) * _BLOCK_S
-    f32 = jnp.float32
+    _, _, S_pad = _blocks(K, S)
+    B_pad = 1 << (B - 1).bit_length()
+    f32 = np.float32
+    tt = np.full((B_pad, S_pad, K), np.inf, f32)
+    with np.errstate(divide="ignore"):
+        tt[:B, :S, :] = 8.0 * batch.sizes[:, None, :] / np.swapaxes(
+            batch.bw, 1, 2)
 
-    def site_pad(a, fill=0.0):
-        if S_pad == S:
-            return jnp.asarray(a, f32)
-        out = np.full(a.shape[:-1] + (S_pad,), fill, dtype=np.float32)
-        out[..., :S] = a
-        return jnp.asarray(out)
+    def jobs(a, dtype=f32):
+        out = np.zeros((B_pad, 1, K), dtype)
+        out[:B, 0, :] = a
+        return out
+
+    def sites(a):
+        out = np.zeros((B_pad, S_pad, 1), f32)
+        out[:B, :S, 0] = a
+        return out
 
     free_pen = np.where(batch.free_slots <= 0,
                         -params.queue_penalty_s, 0.0)
-    interpret = jax.default_backend() != "tpu"
-    fn = _pallas_fn(B, K, S_pad, float(params.alpha), float(params.gamma),
-                    float(params.beta * params.queue_penalty_s),
-                    float(params.min_benefit_s), float(params.ppf_sigma),
-                    params.use_stoch, interpret)
-    out = fn(jnp.asarray(batch.sizes, f32), jnp.asarray(batch.t_loads, f32),
-             jnp.asarray(batch.rem, f32), jnp.asarray(batch.cur_green, f32),
-             jnp.asarray(batch.load_src, f32),
-             jnp.asarray(batch.s_i, jnp.int32), site_pad(batch.bw),
-             site_pad(batch.W), site_pad(batch.bq_load), site_pad(free_pen))
-    return np.asarray(out)
+    key = (B_pad, K, S_pad, float(params.alpha), float(params.gamma),
+           float(params.beta * params.queue_penalty_s),
+           float(params.min_benefit_s), float(params.ppf_sigma),
+           params.use_stoch)
+    return key, [tt, jobs(batch.t_loads), jobs(batch.rem),
+                 jobs(batch.cur_green), jobs(batch.load_src),
+                 jobs(batch.s_i, np.int32), sites(batch.W),
+                 sites(batch.bq_load), sites(free_pen)]
+
+
+def _score_pallas(batch: ScoreBatch, params: ScoreParams) -> np.ndarray:
+    """The tiled kernel in float32 (``interpret=True`` off-TPU)."""
+    import jax
+    import jax.numpy as jnp
+
+    key, args = _pallas_inputs(batch, params)
+    fn = _pallas_fn(*key, jax.default_backend() != "tpu")
+    out = fn(*(jnp.asarray(a) for a in args))
+    return np.asarray(out)[:batch.sizes.shape[0], 0, :]
 
 
 # ---------------------------------------------------------------------------

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import copy
 import math
+import multiprocessing
 import os
 import time
 from concurrent.futures import ProcessPoolExecutor
@@ -321,12 +322,23 @@ def run_cells_batched(cells: Sequence[tuple], *,
     return SweepResult(runs=runs, wall_s=time.perf_counter() - t0, workers=1)
 
 
+def _host_only_worker() -> None:
+    """Pool-worker initializer: keep JAX (and so the decide backend) on
+    the host CPU.  An accelerator belongs to one process, and the parent
+    may already hold it; device work stays in the parent."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
 def run_cells(cells: Sequence[tuple], *, workers: Optional[int] = None,
               keep_results: bool = True) -> SweepResult:
     """Execute prepared cells (see :meth:`SweepSpec.cells`) and merge in
     submission order.  ``workers=1`` (or a single cell) runs inline —
     no pool, no pickling; ``workers=None`` sizes the pool to
-    ``min(len(cells), cpu_count)``."""
+    ``min(len(cells), cpu_count)``.  Workers are spawned (not forked
+    from a parent that may hold an accelerator) and run on the CPU."""
     t0 = time.perf_counter()
     if workers is None:
         workers = min(len(cells), os.cpu_count() or 1)
@@ -334,7 +346,10 @@ def run_cells(cells: Sequence[tuple], *, workers: Optional[int] = None,
     if workers == 1:
         results = [_run_cell(c) for c in cells]
     else:
-        with ProcessPoolExecutor(max_workers=workers) as ex:
+        with ProcessPoolExecutor(
+                max_workers=workers,
+                mp_context=multiprocessing.get_context("spawn"),
+                initializer=_host_only_worker) as ex:
             # map() yields in submission order — completion order never
             # leaks into the merge
             results = list(ex.map(_run_cell, cells))

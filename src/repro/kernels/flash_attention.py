@@ -1,12 +1,19 @@
-"""Pallas TPU flash attention (forward), VMEM-tiled online softmax.
+"""Pallas TPU flash attention, VMEM-tiled online softmax.
 
 TPU-native adaptation (DESIGN.md §8): q tiles of BLOCK_Q=256 rows stream
 through VMEM while the kv reduction runs along the innermost grid axis;
 (m, l, acc) online-softmax carries live in VMEM scratch across kv steps.
-All matmul tile dims are multiples of the 128-lane MXU systolic width.
-Supports causal masking, sliding windows (gemma2 local layers), GQA head
-grouping via BlockSpec index maps, and tanh soft-capping — fused, so the
-masked QK^T logits never round-trip to HBM.
+The kernel works head-major — ``(b, nh, s, hd)`` with ``(bq, hd)`` tiles,
+so a tile's last two dims are a sequence slice (a multiple of 8, or the
+whole sequence) and the whole head dim — and the public wrapper keeps
+the model's ``(b, s, nh, hd)`` layout.  Supports causal masking, sliding
+windows (gemma2 local layers), GQA head grouping via BlockSpec index
+maps, and tanh soft-capping — fused, so the masked QK^T logits never
+round-trip to HBM.
+
+The backward pass is the oracle's (``kernels/ref.py``) through
+``jax.vjp``, attached with ``jax.custom_vjp``: ``jax.grad`` of a train
+step differentiates the same math the kernel computes forward.
 
 Validated against kernels/ref.py in interpret mode (CPU) by
 tests/test_kernels.py; selected automatically on TPU by kernels/ops.py.
@@ -14,12 +21,13 @@ tests/test_kernels.py; selected automatically on TPU by kernels/ops.py.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import ref as ref_lib
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
@@ -27,12 +35,12 @@ NEG_INF = -2.0e38
 
 
 def _attn_kernel(
-    q_ref,  # (1, bq, 1, hd)
-    k_ref,  # (1, bk, 1, hd)
-    v_ref,  # (1, bk, 1, hd)
-    o_ref,  # (1, bq, 1, hd)
-    m_scr,  # (bq,) f32  running max
-    l_scr,  # (bq,) f32  running denom
+    q_ref,  # (bq, hd)
+    k_ref,  # (bk, hd)
+    v_ref,  # (bk, hd)
+    o_ref,  # (bq, hd)
+    m_scr,  # (bq, 1) f32  running max
+    l_scr,  # (bq, 1) f32  running denom
     acc_scr,  # (bq, hd) f32  running numerator
     *,
     mask_kind: str,
@@ -51,9 +59,9 @@ def _attn_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]  # (bq, hd)
-    k = k_ref[0, :, 0, :]  # (bk, hd)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[...]
+    k = k_ref[...]
+    v = v_ref[...]
     hd = q.shape[-1]
 
     s = jax.lax.dot_general(
@@ -71,21 +79,88 @@ def _attn_kernel(
         s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_scr[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])  # (bq, bk)
-    l_cur = l_scr[...] * alpha + jnp.sum(p, axis=1)
+    p = jnp.exp(s - m_cur)  # (bq, bk)
+    l_cur = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
     pv = jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
+    acc_scr[...] = acc_scr[...] * alpha + pv
     m_scr[...] = m_cur
     l_scr[...] = l_cur
 
     @pl.when(ki == n_k_blocks - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
+
+
+def _forward(q, k, v, mask_kind, window, attn_softcap, block_q, block_k,
+             interpret):
+    """The kernel on head-major operands: q (b, nh, s, hd), k/v
+    (b, nkv, t, hd) -> (b, nh, s, hd)."""
+    b, nh, s, hd = q.shape
+    nkv, t = k.shape[1], k.shape[2]
+    group = nh // nkv
+    block_q = min(block_q, s)
+    block_k = min(block_k, t)
+    assert s % block_q == 0 and t % block_k == 0, (s, t, block_q, block_k)
+    n_q = s // block_q
+    n_k = t // block_k
+
+    kernel = functools.partial(
+        _attn_kernel,
+        mask_kind=mask_kind, window=window, attn_softcap=attn_softcap,
+        block_q=block_q, block_k=block_k, n_k_blocks=n_k,
+    )
+    # batch and head dims squeezed: the kernel sees (bq, hd) / (bk, hd)
+    q_spec = pl.BlockSpec((None, None, block_q, hd),
+                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, hd),
+                           lambda bi, hi, qi, ki: (bi, hi // group, ki, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(b, nh, n_q, n_k),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, nh, s, hd), q.dtype),
+        scratch_shapes=[
+            # (bq, 1) m, (bq, 1) l, (bq, hd) acc — f32 online-softmax carries
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
+        ],
+        interpret=interpret,
+    )(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _attention(q, k, v, mask_kind, window, attn_softcap, block_q, block_k,
+               interpret):
+    """(b, s, nh, hd) in and out; the kernel runs head-major."""
+    out = _forward(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                   v.transpose(0, 2, 1, 3), mask_kind, window, attn_softcap,
+                   block_q, block_k, interpret)
+    return out.transpose(0, 2, 1, 3)
+
+
+def _attention_fwd(q, k, v, mask_kind, window, attn_softcap, block_q,
+                   block_k, interpret):
+    out = _attention(q, k, v, mask_kind, window, attn_softcap, block_q,
+                     block_k, interpret)
+    return out, (q, k, v)
+
+
+def _attention_bwd(mask_kind, window, attn_softcap, block_q, block_k,
+                   interpret, res, g):
+    ref = functools.partial(ref_lib.flash_attention_ref, mask_kind=mask_kind,
+                            window=window, attn_softcap=attn_softcap)
+    _, vjp = jax.vjp(ref, *res)
+    return vjp(g)
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
 
 
 @functools.partial(
@@ -106,36 +181,5 @@ def flash_attention_pallas(
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
 ) -> jax.Array:
-    b, s, nh, hd = q.shape
-    t, nkv = k.shape[1], k.shape[2]
-    group = nh // nkv
-    block_q = min(block_q, s)
-    block_k = min(block_k, t)
-    assert s % block_q == 0 and t % block_k == 0, (s, t, block_q, block_k)
-    n_q = s // block_q
-    n_k = t // block_k
-
-    grid = (b, nh, n_q, n_k)
-    kernel = functools.partial(
-        _attn_kernel,
-        mask_kind=mask_kind, window=window, attn_softcap=attn_softcap,
-        block_q=block_q, block_k=block_k, n_k_blocks=n_k,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, nh, hd), q.dtype),
-        scratch_shapes=[
-            # (bq,) m, (bq,) l, (bq, hd) acc — f32 online-softmax VMEM carries
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v)
+    return _attention(q, k, v, mask_kind, window, attn_softcap, block_q,
+                      block_k, interpret)

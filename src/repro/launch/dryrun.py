@@ -1,5 +1,4 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 os.environ.setdefault("REPRO_UNROLL_SCAN", "1")  # full-cost accounting (see
 # models/transformer.scan_or_unroll): XLA counts While bodies once.
 """Multi-pod dry-run (assignment §MULTI-POD DRY-RUN item 3) plus the
@@ -47,6 +46,10 @@ from repro.train.train_step import TrainStepConfig, make_train_step
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "benchmarks", "artifacts")
 
+#: host devices the mesh lowering asks XLA's CPU backend for: the
+#: 2x16x16 multi-pod production mesh
+HOST_DEVICES = 512
+
 # TPU v5e constants (assignment §ROOFLINE)
 PEAK_FLOPS = 197e12  # bf16 / chip
 HBM_BW = 819e9  # bytes/s / chip
@@ -92,21 +95,6 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
             continue
         out[kind] = out.get(kind, 0) + _shape_bytes(type_str or "")
     return out
-
-
-def _peak_bytes(mem) -> Optional[int]:
-    """Per-device peak HBM: the runtime stat when jaxlib exposes it, else
-    the conservative sum of live buffer classes (args + outputs + temps +
-    code, minus donated aliases)."""
-    peak = getattr(mem, "peak_memory_in_bytes", None)
-    if peak is not None:
-        return peak
-    parts = [getattr(mem, a, 0) or 0 for a in (
-        "argument_size_in_bytes", "output_size_in_bytes",
-        "temp_size_in_bytes", "generated_code_size_in_bytes")]
-    if not any(parts):
-        return None
-    return sum(parts) - (getattr(mem, "alias_size_in_bytes", 0) or 0)
 
 
 def _pspec_tree(logical_tree, mesh):
@@ -185,8 +173,6 @@ def _compile_once(
                 compiled = lowered.compile()
                 mem = compiled.memory_analysis()
                 cost = compiled.cost_analysis()
-                if isinstance(cost, (list, tuple)):  # older jax: per-program list
-                    cost = cost[0] if cost else {}
     finally:
         if prev is None:
             os.environ.pop("REPRO_UNROLL_SCAN", None)
@@ -196,6 +182,18 @@ def _compile_once(
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     return flops, bytes_accessed, coll, mem, compiled
+
+
+def force_host_devices() -> None:
+    """Ask XLA's CPU backend for :data:`HOST_DEVICES` devices, keeping
+    every other flag the caller put in ``XLA_FLAGS`` (and a device count
+    the caller chose).  Takes effect only if JAX has not initialized its
+    backends yet in this process."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "--xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={HOST_DEVICES}"
+        ).strip()
 
 
 def _reduced_depth(cfg, k: int):
@@ -229,6 +227,7 @@ def lower_cell(
             "reason": "long_500k requires sub-quadratic attention (DESIGN.md §7)",
         }
     shape = SHAPES[shape_name]
+    force_host_devices()
     mesh = make_production_mesh(multi_pod=multi_pod)
     rules = rules or DEFAULT_RULES
     t0 = time.time()
@@ -269,11 +268,11 @@ def lower_cell(
         "collective_bytes_per_device": coll_total,
         "collectives": coll,
         "memory": {
-            "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
-            "output_bytes": getattr(mem, "output_size_in_bytes", None),
-            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
-            "peak_bytes": _peak_bytes(mem),
-            "generated_code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
+            "argument_bytes": mem.argument_size_in_bytes,
+            "output_bytes": mem.output_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes,
+            "peak_bytes": mem.peak_memory_in_bytes,
+            "generated_code_bytes": mem.generated_code_size_in_bytes,
         },
         # roofline terms (seconds, per §ROOFLINE — per-chip quantities)
         "t_compute_s": flops / PEAK_FLOPS,

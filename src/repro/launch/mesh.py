@@ -4,12 +4,16 @@ A FUNCTION, not a module constant: importing this module never touches jax
 device state."""
 from __future__ import annotations
 
-# the version-gated jax.make_mesh wrapper (AxisType is absent at the jax
-# pin); re-exported here because launch-layer callers import it from this
-# module
-from repro.parallel.compat import make_mesh
+import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_local_mesh", "make_mesh", "make_production_mesh"]
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axis types (sharding follows the
+    logical-axis rules, not explicit per-op annotations)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 
 
@@ -163,6 +164,7 @@ def main(argv=None):
                     help="serving-plane router for the simulated horizon "
                          "(see repro.core.serving.available_routers)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.green_route > 0:
         # t=0 view: the snapshot router over one shared ClusterState —

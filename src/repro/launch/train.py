@@ -9,6 +9,10 @@ Two modes:
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch micro-lm --steps 100
+  PYTHONPATH=src python -m repro.launch.train --arch micro-lm --steps 100 \
+      --max-steps 40 --ckpt-dir ckpt && \
+  PYTHONPATH=src python -m repro.launch.train --arch micro-lm --steps 100 \
+      --resume --ckpt-dir ckpt
   PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b --smoke --steps 20
 """
 from __future__ import annotations
@@ -24,6 +28,7 @@ from repro.configs import SHAPES, get_config
 from repro.core.traces import generate_trace
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import SyntheticLMDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.train.train_step import TrainStepConfig
@@ -39,6 +44,11 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--save-every", type=int, default=50)
+    ap.add_argument("--log-every", type=int, default=25)
+    ap.add_argument("--max-steps", type=int, default=None,
+                    help="stop this session after this many steps and "
+                         "checkpoint there (a later --resume continues to "
+                         "--steps on the same schedule)")
     ap.add_argument("--ckpt-mode", default="full", choices=["full", "int8", "delta-int8"])
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--resume", action="store_true")
@@ -49,6 +59,7 @@ def main(argv=None):
                     help="drive the preemption trace from a registered "
                          "scenario (see repro.core.scenarios)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -79,6 +90,7 @@ def main(argv=None):
             total_steps=args.steps,
             save_every=args.save_every,
             ckpt_mode=args.ckpt_mode,
+            log_every=args.log_every,
             step_cfg=TrainStepConfig(
                 opt=AdamWConfig(lr=args.lr),
                 grad_compress=args.grad_compress,
@@ -94,7 +106,10 @@ def main(argv=None):
             print(f"[train] resumed from step {step}")
         except FileNotFoundError:
             trainer.init_state()
-    status = trainer.run()
+    status = trainer.run(max_steps=args.max_steps)
+    if status["status"] == "done" and trainer.step < args.steps:
+        trainer.save()  # session budget spent: checkpoint for --resume
+        status["ckpt_bytes"] = ckpt.latest_bytes
     print("[train] history:")
     for row in trainer.history:
         print("  ", json.dumps(row))

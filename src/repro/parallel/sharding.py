@@ -105,11 +105,9 @@ def _mesh_axis_names() -> Tuple[str, ...]:
     env_mesh = pxla.thread_resources.env.physical_mesh
     if not env_mesh.empty:
         return tuple(env_mesh.axis_names)
-    # 3) abstract mesh (explicit-axis-type meshes; version-gated in
-    # repro.parallel.compat — the API is absent at the jax pin)
-    from repro.parallel.compat import abstract_mesh_axis_names
-
-    return abstract_mesh_axis_names()
+    # 3) abstract mesh (explicit-axis-type mesh contexts)
+    am = jax.sharding.get_abstract_mesh()
+    return () if am.empty else tuple(am.axis_names)
 
 
 @contextlib.contextmanager

@@ -1,5 +1,7 @@
 """Per-kernel allclose sweeps against the pure-jnp oracles (interpret mode
 executes the Pallas kernel body on CPU, per the assignment)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -61,6 +63,30 @@ def test_flash_attention_block_shapes():
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6, rtol=2e-6)
 
 
+@pytest.mark.parametrize("mask,win,cap,nkv", [
+    ("causal", 0, 0.0, 4),
+    ("window", 64, 30.0, 2),
+    ("full", 0, 0.0, 1),
+])
+def test_flash_attention_grad_matches_oracle(mask, win, cap, nkv):
+    """jax.grad through the kernel (its custom_vjp) equals the oracle's
+    gradient, for q, k and v."""
+    q, k, v = _qkv(jax.random.PRNGKey(4), 2, 128, 128, 4, nkv, 64, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(5), q.shape, jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, mask_kind=mask, window=win, attn_softcap=cap) * w)
+
+    pallas = functools.partial(flash_attention_pallas, block_q=64,
+                               block_k=64, interpret=True)
+    got = jax.grad(loss(pallas), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref.flash_attention_ref), (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=1e-5, rtol=1e-5)
+
+
 def test_ops_dispatch_ref_on_cpu():
     """On this CPU container the default impl must be the oracle itself."""
     q, k, v = _qkv(jax.random.PRNGKey(2), 1, 64, 64, 2, 2, 32, jnp.float32)
@@ -73,6 +99,8 @@ def test_ops_dispatch_ref_on_cpu():
     (256 * 64, jnp.float32),
     (256 * 64 * 4, jnp.float32),
     (256 * 128, jnp.bfloat16),
+    (256 * 100, jnp.float32),  # rows not a whole number of row tiles
+    (256 * 5, jnp.float32),    # fewer rows than one tile
 ])
 def test_quantize_matches_oracle(n, dtype):
     x = (jax.random.normal(jax.random.PRNGKey(3), (n,), jnp.float32) * 3).astype(dtype)

@@ -211,3 +211,35 @@ if HAS_HYPOTHESIS:
         states = [random_state(seed * 17 + i) for i in range(4)]
         assert pol.decide_batch(states) == [
             pol.decide_scalar(s) for s in states]
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_pallas_multi_tile_matches_numpy(monkeypatch, seed):
+    """With tiles smaller than the batch (several job tiles on lanes,
+    several site tiles on sublanes), the running argbest across site
+    tiles and the dead padded lanes still give numpy's destinations —
+    ties included (sites share windows, loads and bandwidth)."""
+    monkeypatch.setattr(pk, "_BLOCK_J", 128)
+    monkeypatch.setattr(pk, "_BLOCK_S", 8)
+    pk._pallas_fn.cache_clear()
+    rng = np.random.default_rng(seed)
+    B, k, n = 3, 300, 21
+    rows = [pk.StateRows(
+        sizes=rng.choice([5.0, 20.0, 60.0], k) * GB,
+        t_loads=np.full(k, 10.3), rem=rng.uniform(0, 12, k) * HOUR,
+        cur_green=np.where(rng.random(k) < 0.3, 2 * HOUR, 0.0),
+        load_src=rng.choice([0.25, 0.5, 1.0], k),
+        s_i=rng.integers(0, n, k),
+        bw=rng.choice([0.0, 1e9, 10e9], (k, n)),
+        W=rng.choice([0.0, 3 * HOUR, 6 * HOUR], n),
+        bq_load=rng.choice([0.0, 0.25, 0.5], n),
+        free_slots=rng.integers(-1, 3, n)) for _ in range(B)]
+    batch = pk.build_batch(rows)
+    params = FeasibilityAwarePolicy()._params()
+    want = pk.score_batch(batch, params, "numpy")
+    try:
+        got = pk.score_batch(batch, params, "pallas")
+    finally:
+        pk._pallas_fn.cache_clear()
+    assert (want >= 0).any()
+    np.testing.assert_array_equal(got, want)

@@ -169,3 +169,59 @@ def test_orchestration_plan_preview():
     assert len(state.sites) == 5
     assert len(state.jobs) > 0
     assert all(isinstance(a, Action) for a in actions)
+
+
+def _train_losses(capsys, argv):
+    """Run the training launcher; {step: loss} from the history it prints."""
+    import json
+
+    from repro.launch import train
+
+    assert train.main(argv) == 0
+    out = capsys.readouterr().out
+    return out, {r["step"]: r["loss"] for r in map(
+        json.loads, (ln for ln in out.splitlines()
+                     if ln.strip().startswith("{")))}
+
+
+def test_train_launcher_split_session_migrates_and_resumes_exactly(
+        tmp_path, capsys):
+    """launch/train.py --max-steps checkpoints mid-schedule; after
+    migrate_job to another site directory, --resume there continues to
+    --steps with exactly the losses of an uninterrupted run."""
+    common = ["--arch", "micro-lm", "--smoke", "--steps", "4", "--batch",
+              "2", "--seq", "16", "--log-every", "1", "--save-every", "4"]
+    _, ref = _train_losses(
+        capsys, common + ["--ckpt-dir", str(tmp_path / "ref")])
+    _, first = _train_losses(capsys, common + [
+        "--ckpt-dir", str(tmp_path / "A"), "--max-steps", "2"])
+    src = CheckpointManager(str(tmp_path / "A"), job="micro-lm-smoke")
+    assert src.latest.step == 2
+    migrate_job(src, str(tmp_path / "B"))
+    out, second = _train_losses(capsys, common + [
+        "--ckpt-dir", str(tmp_path / "B"), "--resume"])
+    assert "resumed from step 2" in out
+    assert sorted(ref) == [1, 2, 3, 4]
+    assert first == {s: ref[s] for s in (1, 2)}
+    assert second == {s: ref[s] for s in (3, 4)}
+
+
+@pytest.mark.parametrize("where", ["cpu", "alone"])
+def test_chip_smoke_refuses_without_chip_or_repo(tmp_path, where):
+    """chip_smoke.py runs only on a TPU and only from a checkout: on the
+    CPU, or copied out of the repository, it exits non-zero and prints
+    no result line."""
+    import shutil
+    import subprocess
+    import sys
+
+    script = os.path.join(os.path.dirname(__file__), "..", "chip_smoke.py")
+    if where == "alone":
+        script = shutil.copy(script, tmp_path / "chip_smoke.py")
+    out = subprocess.run(
+        [sys.executable, str(script)], capture_output=True, text=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=tmp_path,
+        timeout=300)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert ("no TPU found" if where == "cpu" else "no src/repro") in out.stderr
