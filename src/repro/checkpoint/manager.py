@@ -10,13 +10,13 @@ import json
 import os
 import shutil
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.checkpoint import serializer as ser
 
 
@@ -27,7 +27,6 @@ class CheckpointInfo:
     path: str
     nbytes: int
     mode: str
-    wall_time_s: float
 
 
 class CheckpointManager:
@@ -65,7 +64,7 @@ class CheckpointManager:
                 if os.path.exists(p):
                     step = int(name.split("_")[1])
                     self._history.append(
-                        CheckpointInfo(self.job, step, p, os.path.getsize(p), "?", 0.0)
+                        CheckpointInfo(self.job, step, p, os.path.getsize(p), "?")
                     )
 
     # -- API ------------------------------------------------------------------
@@ -88,40 +87,43 @@ class CheckpointManager:
         """Serialize + persist `state` (any pytree: params or full train
         state). delta-int8 uses the previous save as base."""
         mode = mode or self.mode
-        t0 = time.time()
-        host_state = jax.tree.map(np.asarray, state)  # device->host (gather)
-        base = self._base_cache if mode == "delta-int8" else None
-        if mode == "delta-int8" and base is None:
-            mode = "int8"  # first checkpoint has no base
+        with telemetry.span("ckpt.save"):
+            with telemetry.span("ckpt.save.gather"):  # device -> host
+                host_state = jax.tree.map(np.asarray, state)
+            base = self._base_cache if mode == "delta-int8" else None
+            if mode == "delta-int8" and base is None:
+                mode = "int8"  # first checkpoint has no base
 
-        def _write() -> CheckpointInfo:
-            payload = ser.serialize_tree(host_state, mode=mode, base=base)
-            d = self._step_dir(step)
-            os.makedirs(d, exist_ok=True)
-            path = os.path.join(d, "checkpoint.bin")
-            with open(path, "wb") as f:
-                f.write(ser.to_bytes(payload))
-            info = CheckpointInfo(self.job, step, path, os.path.getsize(path), mode, time.time() - t0)
+            def _write() -> CheckpointInfo:
+                with telemetry.span("ckpt.save.encode"):
+                    raw = ser.to_bytes(
+                        ser.serialize_tree(host_state, mode=mode, base=base))
+                with telemetry.span("ckpt.save.write"):
+                    d = self._step_dir(step)
+                    os.makedirs(d, exist_ok=True)
+                    path = os.path.join(d, "checkpoint.bin")
+                    with open(path, "wb") as f:
+                        f.write(raw)
+                return CheckpointInfo(self.job, step, path, len(raw), mode)
+
+            self.wait()
+            if self.async_save:
+                # host_state is already gathered: the device-side training
+                # loop can proceed while serialization+IO happen off-thread.
+                info = CheckpointInfo(self.job, step, "", 0, mode)
+
+                def run():
+                    done = _write()
+                    info.path, info.nbytes = done.path, done.nbytes
+
+                self._pending = threading.Thread(target=run, daemon=True)
+                self._pending.start()
+            else:
+                info = _write()
+            self._base_cache = host_state
+            self._history.append(info)
+            self._gc()
             return info
-
-        self.wait()
-        if self.async_save:
-            # host_state is already gathered: the device-side training loop
-            # can proceed while serialization+IO happen off-thread.
-            info = CheckpointInfo(self.job, step, "", 0, mode, 0.0)
-
-            def run():
-                done = _write()
-                info.path, info.nbytes, info.wall_time_s = done.path, done.nbytes, done.wall_time_s
-
-            self._pending = threading.Thread(target=run, daemon=True)
-            self._pending.start()
-        else:
-            info = _write()
-        self._base_cache = host_state
-        self._history.append(info)
-        self._gc()
-        return info
 
     def restore(
         self,
@@ -140,15 +142,18 @@ class CheckpointManager:
         if not infos:
             raise FileNotFoundError(f"no checkpoint for {self.job} step={step}")
         info = infos[-1]
-        with open(info.path, "rb") as f:
-            payload = ser.from_bytes(f.read())
-        if payload.manifest["mode"] == "delta-int8" and base is None:
-            base = self._base_cache
-        tree = ser.deserialize_tree(payload, like, base=base)
-        if shardings is not None:
-            tree = jax.tree.map(
-                lambda x, s: jax.device_put(x, s), tree, shardings
-            )
+        with telemetry.span("ckpt.restore"):
+            with telemetry.span("ckpt.restore.read"):
+                with open(info.path, "rb") as f:
+                    payload = ser.from_bytes(f.read())
+            if payload.manifest["mode"] == "delta-int8" and base is None:
+                base = self._base_cache
+            with telemetry.span("ckpt.restore.decode"):
+                tree = ser.deserialize_tree(payload, like, base=base)
+                if shardings is not None:
+                    tree = jax.tree.map(
+                        lambda x, s: jax.device_put(x, s), tree, shardings
+                    )
         return tree, info
 
     def _gc(self):
@@ -171,5 +176,5 @@ class CheckpointManager:
         path = os.path.join(d, "checkpoint.bin")
         with open(path, "wb") as f:
             f.write(raw)
-        mgr._history.append(CheckpointInfo(job, step, path, len(raw), "?", 0.0))
+        mgr._history.append(CheckpointInfo(job, step, path, len(raw), "?"))
         return mgr

@@ -49,9 +49,9 @@ def migrate_job(
     realtime: bool = False,
 ) -> tuple[CheckpointManager, MigrationReport]:
     """Move the newest checkpoint of `src` to `dst_root` over a WAN model."""
-    t0 = time.time()
+    t0 = time.perf_counter()
     raw = src.export_bytes()
-    t_ser = time.time() - t0
+    t_ser = time.perf_counter() - t0
     nbytes = len(raw)
     t_transfer = float(fz.transfer_time_s(nbytes, bandwidth_bps))
     if realtime:

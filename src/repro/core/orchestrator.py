@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Type
 
 import numpy as np
 
+from repro import telemetry
 from repro.core import feasibility as fz
 from repro.core import policy_kernels as pk
 from repro.core.actions import Action, Defer, Migrate, Pause, Resume, Throttle
@@ -639,54 +640,65 @@ class FeasibilityAwarePolicy(Policy):
         backend is selected); rows decided after a same-tick reservation
         (rare) fall back to the scalar stage 2.  Emits exactly the Action
         list of :meth:`decide_scalar`."""
-        cand = self._prep(state)
-        if cand is None:
-            return []
-        soa = state.soa
-        bw = self._fault_bw(state, soa.site[cand])
-        if pk.backend() != "numpy":
-            dest0 = pk.score_rows([pk.rows_from_state(state, cand, bw)],
-                                  self._params())[0]
-            return self._commit(state, cand, dest0, None, None, bw)
-        ok, tt, dest0 = score_migrations(
-            state, cand,
-            bw if bw is not None else state.bandwidth_bps[soa.site[cand], :],
-            alpha=self.alpha, eps=self.eps,
-            forecast_sigma_s=self.forecast_sigma_s, gamma=self.gamma,
-            beta=self.beta, queue_penalty_s=self.queue_penalty_s,
-            min_benefit_s=self.min_benefit_s)
-        if dest0 is None:
-            return []
-        return self._commit(state, cand, dest0, ok, tt, bw)
+        with telemetry.span("decide"):
+            with telemetry.span("decide.prep"):
+                cand = self._prep(state)
+                if cand is None:
+                    return []
+                soa = state.soa
+                bw = self._fault_bw(state, soa.site[cand])
+                compiled = pk.backend() != "numpy"
+                if compiled:
+                    rows = pk.rows_from_state(state, cand, bw)
+            if compiled:
+                dest0 = pk.score_rows([rows], self._params())[0]
+                ok = tt = None
+            else:
+                ok, tt, dest0 = score_migrations(
+                    state, cand,
+                    bw if bw is not None
+                    else state.bandwidth_bps[soa.site[cand], :],
+                    alpha=self.alpha, eps=self.eps,
+                    forecast_sigma_s=self.forecast_sigma_s, gamma=self.gamma,
+                    beta=self.beta, queue_penalty_s=self.queue_penalty_s,
+                    min_benefit_s=self.min_benefit_s)
+                if dest0 is None:
+                    return []
+            with telemetry.span("decide.commit"):
+                return self._commit(state, cand, dest0, ok, tt, bw)
 
     def decide_batch(self, states: Sequence[ClusterState]) -> List[List[Action]]:
         """All cells' candidate rows scored in ONE fused kernel pass
         (bit-identical to per-cell :meth:`decide` — see
         :mod:`repro.core.policy_kernels` on padding lanes)."""
-        cands = [self._prep(s) for s in states]
-        live = [i for i, c in enumerate(cands) if c is not None]
-        bws = [self._fault_bw(states[i], states[i].soa.site[cands[i]])
-               for i in live]
-        if any(b is not None for b in bws):
-            # batch_from_states takes bw_grids all-or-nothing: fill the
-            # unmasked cells with their advertised rows (element-identical)
-            bws = [b if b is not None
-                   else np.asarray(states[i].bandwidth_bps)[
-                       states[i].soa.site[cands[i]], :]
-                   for i, b in zip(live, bws)]
-        else:
-            bws = None
-        dests = iter(pk.score_states([states[i] for i in live],
-                                     [cands[i] for i in live],
-                                     self._params(), bws))
-        bw_by_cell = dict(zip(live, bws)) if bws is not None else {}
-        out: List[List[Action]] = []
-        for i, (s, c) in enumerate(zip(states, cands)):
-            d0 = None if c is None else next(dests)
-            out.append([] if d0 is None
-                       else self._commit(s, c, d0, None, None,
-                                         bw_by_cell.get(i)))
-        return out
+        with telemetry.span("decide"):
+            with telemetry.span("decide.prep"):
+                cands = [self._prep(s) for s in states]
+                live = [i for i, c in enumerate(cands) if c is not None]
+                bws = [self._fault_bw(states[i], states[i].soa.site[cands[i]])
+                       for i in live]
+                if any(b is not None for b in bws):
+                    # batch_from_states takes bw_grids all-or-nothing: fill
+                    # the unmasked cells with their advertised rows
+                    # (element-identical)
+                    bws = [b if b is not None
+                           else np.asarray(states[i].bandwidth_bps)[
+                               states[i].soa.site[cands[i]], :]
+                           for i, b in zip(live, bws)]
+                else:
+                    bws = None
+            dests = iter(pk.score_states([states[i] for i in live],
+                                         [cands[i] for i in live],
+                                         self._params(), bws))
+            bw_by_cell = dict(zip(live, bws)) if bws is not None else {}
+            out: List[List[Action]] = []
+            with telemetry.span("decide.commit"):
+                for i, (s, c) in enumerate(zip(states, cands)):
+                    d0 = None if c is None else next(dests)
+                    out.append([] if d0 is None
+                               else self._commit(s, c, d0, None, None,
+                                                 bw_by_cell.get(i)))
+            return out
 
     def decide_scalar(self, state: ClusterState) -> List[Action]:
         """The per-job reference implementation (parity oracle for

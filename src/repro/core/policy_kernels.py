@@ -51,12 +51,14 @@ first-occurrence rule, preserving the scalar tie-break key
 from __future__ import annotations
 
 import functools
+import operator
 import os
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import telemetry
 from repro.core import feasibility as fz
 
 # ---------------------------------------------------------------------------
@@ -376,8 +378,9 @@ def score_states(states: Sequence, cands: Sequence[np.ndarray],
     scale, and the check is one batched reduction here)."""
     if not states:
         return []
-    dest = score_batch(batch_from_states(states, cands, bw_grids),
-                       params, backend_name)
+    with telemetry.span("decide.batch"):
+        batch = batch_from_states(states, cands, bw_grids)
+    dest = score_batch(batch, params, backend_name)
     live = (dest >= 0).any(axis=1)
     return [dest[b, :len(c)] if live[b] else None
             for b, c in enumerate(cands)]
@@ -477,21 +480,34 @@ def _jit_fn():
     return _JIT_FN
 
 
+def _count_lanes(batch: ScoreBatch, lanes: int) -> None:
+    """Counters ``decide.rows`` (the batch's unpadded job x site pairs)
+    and ``decide.lanes`` (the pairs a backend computes, padding
+    included), summed only while telemetry records."""
+    if telemetry.active():
+        telemetry.count("decide.rows", sum(map(operator.mul, batch.n_jobs,
+                                               batch.n_sites)))
+        telemetry.count("decide.lanes", lanes)
+
+
 def _score_jit(batch: ScoreBatch, params: ScoreParams) -> np.ndarray:
     """One fused XLA dispatch in float64 (scalar knobs are traced, so
     value changes never recompile; only padding-bucket shape changes
     do)."""
     import jax
 
+    _count_lanes(batch, batch.bw.size)
     with jax.enable_x64(True):
-        out = _jit_fn()(
-            batch.sizes, batch.t_loads, batch.rem, batch.cur_green,
-            batch.load_src, batch.s_i, batch.bw, batch.W, batch.bq_load,
-            batch.free_slots, params.alpha, params.gamma,
-            params.beta * params.queue_penalty_s, params.queue_penalty_s,
-            params.min_benefit_s, params.ppf_sigma,
-            use_stoch=params.use_stoch)
-    return np.asarray(out)
+        with telemetry.span("decide.launch"):
+            out = _jit_fn()(
+                batch.sizes, batch.t_loads, batch.rem, batch.cur_green,
+                batch.load_src, batch.s_i, batch.bw, batch.W, batch.bq_load,
+                batch.free_slots, params.alpha, params.gamma,
+                params.beta * params.queue_penalty_s, params.queue_penalty_s,
+                params.min_benefit_s, params.ppf_sigma,
+                use_stoch=params.use_stoch)
+        with telemetry.span("decide.fetch"):
+            return np.asarray(out)
 
 
 # ---------------------------------------------------------------------------
@@ -624,6 +640,7 @@ def _pallas_fn(B: int, K: int, S: int, alpha: float, gamma: float,
                         pltpu.VMEM((1, block_j), jnp.float32),
                         pltpu.VMEM((1, block_j), jnp.int32)],
         interpret=interpret,
+        name="_dest_kernel",
     )
     return jax.jit(call)
 
@@ -678,10 +695,16 @@ def _score_pallas(batch: ScoreBatch, params: ScoreParams) -> np.ndarray:
     import jax
     import jax.numpy as jnp
 
-    key, args = _pallas_inputs(batch, params)
-    fn = _pallas_fn(*key, jax.default_backend() != "tpu")
-    out = fn(*(jnp.asarray(a) for a in args))
-    return np.asarray(out)[:batch.sizes.shape[0], 0, :]
+    with telemetry.span("decide.batch"):
+        key, args = _pallas_inputs(batch, params)
+    _count_lanes(batch, args[0].size)
+    with telemetry.span("decide.put"):
+        args = [jnp.asarray(a) for a in args]
+    with telemetry.span("decide.launch"):
+        out = _pallas_fn(*key, jax.default_backend() != "tpu")(*args)
+    with telemetry.span("decide.fetch"):
+        out = np.asarray(out)
+    return out[:batch.sizes.shape[0], 0, :]
 
 
 # ---------------------------------------------------------------------------
@@ -705,7 +728,9 @@ def score_rows(rows: Sequence[StateRows], params: ScoreParams,
     un-padded ``(k_i,)`` destination array per cell."""
     if not rows:
         return []
-    dest = score_batch(build_batch(rows), params, backend_name)
+    with telemetry.span("decide.batch"):
+        batch = build_batch(rows)
+    dest = score_batch(batch, params, backend_name)
     return [dest[b, :r.k] for b, r in enumerate(rows)]
 
 

@@ -62,6 +62,7 @@ def quantize_int8_pallas(x: jax.Array, *, block: int = BLOCK, interpret: bool = 
             jax.ShapeDtypeStruct((rows_pad, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="_quant_kernel",
     )(x2)
     return q2[:rows].reshape(n), s[:rows].reshape(rows)
 
@@ -82,6 +83,7 @@ def dequantize_int8_pallas(q: jax.Array, scale: jax.Array, *, block: int = BLOCK
         out_specs=pl.BlockSpec((grid_rows, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, block), jnp.float32),
         interpret=interpret,
+        name="_dequant_kernel",
     )(jnp.pad(q.reshape(rows, block), pad),
       jnp.pad(scale.reshape(rows, 1), pad, constant_values=1.0))
     return x2[:rows].reshape(n)

@@ -8,13 +8,13 @@ fault-tolerance contract for 1000+-node deployments.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import SyntheticLMDataset
 from repro.models.model import Model
@@ -68,7 +68,9 @@ class Trainer:
         """Resume from the newest checkpoint (crash restart or migration
         arrival). Returns the restored step."""
         if self.params is None:
-            self.init_state()
+            # a template of the state's structure; its values are replaced
+            with telemetry.span("train.restore_template"):
+                self.init_state()
         like = self.state_tree()
         tree, info = self.ckpt.restore(like, shardings=shardings)
         self.params = tree["params"]
@@ -89,16 +91,16 @@ class Trainer:
             self.step + (max_steps if max_steps is not None else self.cfg.total_steps),
         )
         status = "done"
-        t0 = time.time()
         while self.step < budget:
             if self.preempt_signal(self.step):
                 self.save()
                 status = "preempted"
                 break
-            batch = self.dataset.batch(self.step)
-            self.params, self.opt_state, metrics = self.train_step(
-                self.params, self.opt_state, batch
-            )
+            with jax.profiler.StepTraceAnnotation("train", step_num=self.step):
+                batch = self.dataset.batch(self.step)
+                self.params, self.opt_state, metrics = self.train_step(
+                    self.params, self.opt_state, batch
+                )
             self.step += 1
             if self.step % self.cfg.log_every == 0 or self.step == budget:
                 row = {k: float(v) for k, v in metrics.items()}
@@ -111,7 +113,6 @@ class Trainer:
         return {
             "status": status,
             "step": self.step,
-            "elapsed_s": time.time() - t0,
             "loss": self.history[-1]["loss"] if self.history else float("nan"),
             "ckpt_bytes": self.ckpt.latest_bytes,
         }

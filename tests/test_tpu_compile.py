@@ -62,8 +62,11 @@ def _batch(B, K, S):
 def test_decide_kernel_compiles(one_chip, B, K, S):
     key, args = pk._pallas_inputs(_batch(B, K, S),
                                   FeasibilityAwarePolicy()._params())
-    assert "tpu_custom_call" in _hlo(pk._pallas_fn(*key, False), one_chip,
-                                     *args)
+    hlo = _hlo(pk._pallas_fn(*key, False), one_chip, *args)
+    assert "tpu_custom_call" in hlo
+    # the stable name the trace shows, on the int32 result the benchmark's
+    # decide_kernel_roofline reads
+    assert f"%_dest_kernel.1 = s32[{B},1,{K}]" in hlo
 
 
 def _attention_shapes(batch=8, seq=256):
@@ -83,8 +86,10 @@ def test_flash_attention_compiles_at_micro_lm_100m_widths(one_chip, pass_):
         return jnp.sum(fwd(q, k, v))
 
     fn = fwd if pass_ == "forward" else jax.value_and_grad(loss, (0, 1, 2))
-    assert "tpu_custom_call" in _hlo(jax.jit(fn), one_chip,
-                                     *_attention_shapes())
+    hlo = _hlo(jax.jit(fn), one_chip, *_attention_shapes())
+    assert "tpu_custom_call" in hlo
+    # the benchmark's flash_attn_roofline finds the kernel by this name
+    assert "%flash_attention_pallas." in hlo
 
 
 @pytest.mark.parametrize("kernel", ["quantize", "dequantize"])
@@ -98,4 +103,7 @@ def test_int8_kernels_compile_at_ragged_row_count(one_chip, kernel):
         shapes = (jax.ShapeDtypeStruct((n,), jnp.int8),
                   jax.ShapeDtypeStruct((rows,), jnp.float32))
         fn = dequantize_int8_pallas
-    assert "tpu_custom_call" in _hlo(fn, one_chip, *shapes)
+    hlo = _hlo(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in hlo
+    assert {"quantize": "%_quant_kernel.", "dequantize": "%_dequant_kernel."}[
+        kernel] in hlo
