@@ -6,7 +6,6 @@ Layout: <root>/<job>/step_<N>/ checkpoint.bin  (manifest embedded).
 """
 from __future__ import annotations
 
-import json
 import os
 import shutil
 import threading
@@ -14,7 +13,6 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 import jax
-import numpy as np
 
 from repro import telemetry
 from repro.checkpoint import serializer as ser
@@ -89,27 +87,27 @@ class CheckpointManager:
         mode = mode or self.mode
         with telemetry.span("ckpt.save"):
             with telemetry.span("ckpt.save.gather"):  # device -> host
-                host_state = jax.tree.map(np.asarray, state)
+                host_state = jax.tree.map(ser.host_array, state)
             base = self._base_cache if mode == "delta-int8" else None
             if mode == "delta-int8" and base is None:
                 mode = "int8"  # first checkpoint has no base
 
             def _write() -> CheckpointInfo:
                 with telemetry.span("ckpt.save.encode"):
-                    raw = ser.to_bytes(
-                        ser.serialize_tree(host_state, mode=mode, base=base))
+                    image = ser.encode(host_state, mode=mode, base=base)
                 with telemetry.span("ckpt.save.write"):
                     d = self._step_dir(step)
                     os.makedirs(d, exist_ok=True)
                     path = os.path.join(d, "checkpoint.bin")
                     with open(path, "wb") as f:
-                        f.write(raw)
-                return CheckpointInfo(self.job, step, path, len(raw), mode)
+                        nbytes = ser.write(f, image)
+                return CheckpointInfo(self.job, step, path, nbytes, mode)
 
             self.wait()
             if self.async_save:
                 # host_state is already gathered: the device-side training
-                # loop can proceed while serialization+IO happen off-thread.
+                # loop can proceed while encode and IO happen off-thread,
+                # streaming the raw leaves from host_state itself.
                 info = CheckpointInfo(self.job, step, "", 0, mode)
 
                 def run():
@@ -145,11 +143,11 @@ class CheckpointManager:
         with telemetry.span("ckpt.restore"):
             with telemetry.span("ckpt.restore.read"):
                 with open(info.path, "rb") as f:
-                    payload = ser.from_bytes(f.read())
-            if payload.manifest["mode"] == "delta-int8" and base is None:
+                    manifest, parts = ser.read(f)
+            if manifest["mode"] == "delta-int8" and base is None:
                 base = self._base_cache
             with telemetry.span("ckpt.restore.decode"):
-                tree = ser.deserialize_tree(payload, like, base=base)
+                tree = ser.decode(manifest, parts, like, base=base)
                 if shardings is not None:
                     tree = jax.tree.map(
                         lambda x, s: jax.device_put(x, s), tree, shardings

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager, serialize_tree, deserialize_tree, tree_bytes
-from repro.checkpoint.serializer import from_bytes, to_bytes
+from repro.checkpoint.serializer import from_bytes, read, to_bytes
 from repro.core import feasibility as fz
 from repro.core.migration import migrate_job
 
@@ -23,6 +23,82 @@ def make_tree(seed=0, scale=1.0):
         "emb": {"table": jax.random.normal(ks[2], (1000, 32), jnp.bfloat16)},
         "step": jnp.int32(7),
     }
+
+
+def column_major(x):
+    """``x`` kept column-major on its device, as a TPU train step keeps
+    some of its outputs."""
+    from jax.experimental.layout import Format, Layout
+
+    return jax.device_put(x, Format(Layout((1, 0)), x.sharding))
+
+
+def odd_tree(seed=0, shift=0.0):
+    """Leaves the writer has to take as they come: bfloat16, a 0-d int32
+    step, a zero-size leaf, a transposed and a strided numpy view, and a
+    device array in a column-major layout."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return {
+        "col": column_major(jax.random.normal(ks[4], (24, 40), jnp.float32) + shift),
+        "w": jax.random.normal(ks[0], (130, 64), jnp.float32) + shift,
+        "emb": {"table": jax.random.normal(ks[1], (300, 32), jnp.bfloat16) + shift},
+        "empty": np.zeros((0, 4), np.float32),
+        "wT": (np.asarray(jax.random.normal(ks[2], (40, 24))) + shift).T,
+        "strided": (np.asarray(jax.random.normal(ks[3], (50, 6))) + shift)[::2, 1:5],
+        "step": np.int32(7),
+    }
+
+
+@pytest.mark.parametrize("mode", ["full", "int8", "delta-int8"])
+def test_saved_file_is_the_format_and_restores_owned_leaves(tmp_path, mode):
+    """The file a save streams is the in-memory encoding byte for byte,
+    its size is S_j, and a restore gives arrays of their own: bit-equal
+    where the entry is raw, within the int8 bound where it is not."""
+    base, tree = odd_tree(0), odd_tree(0, shift=0.01)
+    assert not tree["wT"].flags.c_contiguous and not tree["strided"].flags.c_contiguous
+    assert tree["col"].format.layout.major_to_minor == (1, 0)
+    mgr = CheckpointManager(str(tmp_path), job="fmt", mode=mode)
+    mgr.save(1, base)
+    info = mgr.save(2, tree)  # delta-int8 against the first save
+    delta_base = base if mode == "delta-int8" else None
+    with open(info.path, "rb") as f:
+        got = f.read()
+    assert got == to_bytes(serialize_tree(tree, mode=mode, base=delta_base))
+    assert info.nbytes == len(got) == os.path.getsize(info.path)
+    back, _ = mgr.restore(tree, base=delta_base)
+    assert jax.tree.structure(back) == jax.tree.structure(tree)
+    again = deserialize_tree(from_bytes(got), tree, base=delta_base)
+    for x, b, y, z in zip(jax.tree.leaves(tree), jax.tree.leaves(base),
+                          jax.tree.leaves(back), jax.tree.leaves(again)):
+        x = np.asarray(x)
+        assert y.shape == x.shape and y.dtype == x.dtype
+        assert y.flags.writeable and y.flags.c_contiguous and y.flags.owndata
+        np.testing.assert_array_equal(y, z)
+        if mode == "full" or not jnp.issubdtype(x.dtype, jnp.floating):
+            np.testing.assert_array_equal(y, x)
+        elif x.size:
+            x32, y32 = x.astype(np.float32), y.astype(np.float32)
+            ref = np.asarray(b, np.float32) if mode == "delta-int8" else 0.0
+            bound = (np.max(np.abs(x32 - ref)) / 127
+                     + float(jnp.finfo(x.dtype).eps) * np.abs(x32))
+            assert np.all(np.abs(y32 - x32) <= bound)
+
+
+@pytest.mark.parametrize("mode,cut", [("full", "magic"), ("full", "manifest"),
+                                      ("full", "payload"), ("int8", "payload")])
+def test_a_truncated_checkpoint_raises(tmp_path, mode, cut):
+    mgr = CheckpointManager(str(tmp_path), job="cut", mode=mode)
+    info = mgr.save(1, odd_tree())
+    with open(info.path, "rb") as f:
+        raw = f.read()
+    mlen = int.from_bytes(raw[8:16], "little")
+    keep = {"magic": 5, "manifest": 16 + mlen // 2, "payload": len(raw) - 3}[cut]
+    with open(info.path, "wb") as f:
+        f.write(raw[:keep])
+    with pytest.raises(ValueError, match="checkpoint"):
+        mgr.restore(odd_tree())
+    with open(info.path, "rb") as f, pytest.raises(ValueError, match="checkpoint"):
+        read(f)
 
 
 def test_full_roundtrip_exact():

@@ -7,9 +7,12 @@ import os
 from collections import Counter
 
 import jax
+import numpy as np
 import pytest
 
 from repro import telemetry
+from repro.checkpoint import CheckpointManager, tree_bytes
+from repro.checkpoint.serializer import from_bytes
 from repro.core import policy_kernels as pk
 
 DECIDE_CHILDREN = {"decide.prep", "decide.batch", "decide.put",
@@ -204,12 +207,14 @@ def test_save_migrate_restore_records_every_checkpoint_stage(tmp_path):
     assert by["ckpt.save"].end_ns <= by["train.restore_template"].start_ns
     assert 0 < report.t_serialize_s < (by["train.restore_template"].start_ns
                                        - by["ckpt.save"].end_ns) * 1e-9
-    assert telemetry.counters() == {}
+    # the only counters are the checkpoint's own: a full save and its
+    # restore move every payload byte straight, once each way
+    payload = 2 * tree_bytes(a.state_tree())
+    assert telemetry.counters() == {"ckpt.bytes": payload,
+                                    "ckpt.bytes_direct": payload}
 
 
 def test_async_save_records_the_writer_threads_stages_at_its_top(tmp_path):
-    from repro.checkpoint import CheckpointManager
-
     mgr = CheckpointManager(str(tmp_path), async_save=True)
     with telemetry.recording():
         info = mgr.save(5, {"w": jax.numpy.ones((4, 4))})
@@ -222,3 +227,35 @@ def test_async_save_records_the_writer_threads_stages_at_its_top(tmp_path):
     assert got["ckpt.save.encode"].parent == got["ckpt.save.write"].parent == -1
     assert got["ckpt.save.encode"].end_ns <= got["ckpt.save.write"].start_ns
     assert info.nbytes > 0
+
+
+@pytest.mark.parametrize("mode", ["full", "int8", "full-transposed",
+                                  "full-column-major"])
+def test_checkpoint_counts_the_bytes_that_skip_staging(tmp_path, mode):
+    """``ckpt.bytes`` counts every entry's payload on the save's write
+    and on the restore's read; ``ckpt.bytes_direct`` the part that moved
+    between a leaf's own buffer and the file: all of a full checkpoint,
+    a device array kept column-major included (the gather relays it out
+    on the device), none of an int8 one (float leaves only), and of a
+    full save of a transposed numpy leaf only its restore (the save
+    writes it through one contiguous copy)."""
+    from tests.test_checkpoint import column_major
+
+    w = np.arange(96, dtype=np.float32).reshape(8, 12)
+    tree = {"w": {"full-transposed": w.T,
+                  "full-column-major": column_major(jax.numpy.asarray(w))
+                  }.get(mode, w),
+            "b": jax.numpy.ones((7,), jax.numpy.bfloat16)}
+    mgr = CheckpointManager(str(tmp_path), mode=mode.split("-")[0])
+    with telemetry.recording():
+        info = mgr.save(1, tree)
+        mgr.restore(tree)
+    with open(info.path, "rb") as f:
+        payload = len(from_bytes(f.read()).data)
+    got = telemetry.counters()
+    direct = {"int8": 0, "full-transposed": 2 * payload - w.nbytes}.get(
+        mode, 2 * payload)
+    assert got == {"ckpt.bytes": 2 * payload, "ckpt.bytes_direct": direct}
+    mgr.save(2, tree)  # not recording: nothing is counted
+    mgr.restore(tree)
+    assert telemetry.counters() == got
