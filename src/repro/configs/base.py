@@ -52,6 +52,8 @@ SHAPES = {
 #   'mamba'        Mamba-1 selective-SSM mixer + MLP
 #   'mlstm'        xLSTM matrix-LSTM block (self-contained, no separate MLP)
 #   'slstm'        xLSTM scalar-LSTM block (self-contained, gated FFN inside)
+#   'conv'         LFM2 gated short convolution (in_proj -> B, C, x;
+#                  out_proj(C * causal depthwise conv(B * x))) + MLP
 
 
 @dataclass(frozen=True)
@@ -88,6 +90,21 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0  # 0 -> d_ff
     router_aux_coef: float = 0.01
+    # Expert weights held here: ``num_experts`` of them, experts
+    # ``first_expert`` .. ``first_expert + num_experts - 1`` of a router
+    # over ``router_experts`` (0 -> num_experts, every expert held).
+    router_experts: int = 0
+    first_expert: int = 0
+    router_score: str = "softmax"  # 'softmax' | 'sigmoid'
+    router_bias: bool = False  # a selection-only bias per expert
+    norm_topk_prob: bool = True  # chosen weights renormalised to sum 1
+    routed_scaling_factor: float = 1.0
+    # 'dense' | 'capacity' (REPRO_MOE_IMPL chooses when empty) | 'dropless'
+    # (every routed row of the held experts, grouped matrix products)
+    moe_impl: str = ""
+
+    # --- gated short convolution ('conv' blocks) ---
+    conv_kernel: int = 3
 
     # --- mamba (jamba) ---
     mamba_d_state: int = 16
@@ -96,6 +113,7 @@ class ModelConfig:
 
     # --- norm / activation / embeddings ---
     norm_type: str = "rmsnorm"  # 'rmsnorm' | 'layernorm'
+    rms_norm_eps: float = 1e-6  # every norm of the stack, qk_norm included
     act: str = "silu"  # 'silu' | 'gelu'
     tie_embeddings: bool = False
     embed_scale: bool = False  # gemma-style sqrt(d_model) embedding scale
@@ -132,6 +150,10 @@ class ModelConfig:
     @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def num_router_experts(self) -> int:
+        return self.router_experts or self.num_experts
 
     @property
     def is_encdec(self) -> bool:
@@ -201,7 +223,13 @@ def param_count(cfg: ModelConfig) -> int:
         return 3 * d * cfg.d_ff  # SwiGLU (gate, up, down)
 
     def moe_mlp() -> int:
-        return cfg.num_experts * 3 * d * cfg.expert_d_ff + d * cfg.num_experts
+        router = d * cfg.num_router_experts
+        if cfg.router_bias:
+            router += cfg.num_router_experts
+        return cfg.num_experts * 3 * d * cfg.expert_d_ff + router
+
+    def conv_params() -> int:
+        return d * 3 * d + cfg.conv_kernel * d + d * d  # in_proj, taps, out_proj
 
     def mamba_params() -> int:
         d_in = cfg.mamba_expand * d
@@ -238,8 +266,8 @@ def param_count(cfg: ModelConfig) -> int:
                 unit_cost += moe_mlp()
             else:
                 unit_cost += dense_mlp()
-        elif kind == "mamba":
-            unit_cost += mamba_params() + 2 * d
+        elif kind in ("mamba", "conv"):
+            unit_cost += (mamba_params() if kind == "mamba" else conv_params()) + 2 * d
             if cfg.moe and (not cfg.moe_pattern or i in cfg.moe_pattern):
                 unit_cost += moe_mlp()
             else:

@@ -13,7 +13,9 @@ round-trip to HBM.
 
 The backward pass is the oracle's (``kernels/ref.py``) through
 ``jax.vjp``, attached with ``jax.custom_vjp``: ``jax.grad`` of a train
-step differentiates the same math the kernel computes forward.
+step differentiates the same math the kernel computes forward.  It
+materialises the float32 scores, so where they would pass
+``SCORE_BYTES_MAX`` it runs over chunks of batch rows, one after another.
 
 Validated against kernels/ref.py in interpret mode (CPU) by
 tests/test_kernels.py; selected automatically on TPU by kernels/ops.py.
@@ -32,6 +34,9 @@ from repro.kernels import ref as ref_lib
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 NEG_INF = -2.0e38
+#: the largest float32 score tensor (rows x heads x queries x keys) the
+#: backward pass materialises at once
+SCORE_BYTES_MAX = 3 << 29  # 1.5 GiB
 
 
 def _attn_kernel(
@@ -155,12 +160,31 @@ def _attention_fwd(q, k, v, mask_kind, window, attn_softcap, block_q,
     return out, (q, k, v)
 
 
+def bwd_rows(b: int, nh: int, s: int, t: int) -> int:
+    """Batch rows per chunk of the backward pass: the most rows, dividing
+    ``b``, whose scores fit in ``SCORE_BYTES_MAX`` (at least one)."""
+    per_row = 4 * nh * s * t
+    return max(c for c in range(1, b + 1)
+               if b % c == 0 and (c == 1 or c * per_row <= SCORE_BYTES_MAX))
+
+
 def _attention_bwd(mask_kind, window, attn_softcap, block_q, block_k,
                    interpret, res, g):
     ref = functools.partial(ref_lib.flash_attention_ref, mask_kind=mask_kind,
                             window=window, attn_softcap=attn_softcap)
-    _, vjp = jax.vjp(ref, *res)
-    return vjp(g)
+
+    def vjp_of(q, k, v, g):
+        _, vjp = jax.vjp(ref, q, k, v)
+        return vjp(g)
+
+    q, k, v = res
+    b = q.shape[0]
+    rows = bwd_rows(b, q.shape[2], q.shape[1], k.shape[1])
+    if rows == b:
+        return vjp_of(q, k, v, g)
+    chunks = [x.reshape(b // rows, rows, *x.shape[1:]) for x in (q, k, v, g)]
+    grads = jax.lax.map(lambda a: vjp_of(*a), tuple(chunks))
+    return tuple(x.reshape(b, *x.shape[2:]) for x in grads)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)

@@ -100,15 +100,15 @@ def attend_ref(
     return out.reshape(b, s, nh, hd)
 
 
-def _project_qkv(p, x, kv_x, *, qk_norm):
+def _project_qkv(p, x, kv_x, *, qk_norm, norm_eps=1e-6):
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("btd,dhk->bthk", kv_x, p["wk"])
     v = jnp.einsum("btd,dhk->bthk", kv_x, p["wv"])
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     if qk_norm:
-        q = rms_norm_1d(q, p["q_norm"])
-        k = rms_norm_1d(k, p["k_norm"])
+        q = rms_norm_1d(q, p["q_norm"], norm_eps)
+        k = rms_norm_1d(k, p["k_norm"], norm_eps)
     return q, k, v
 
 
@@ -124,9 +124,10 @@ def apply_attention(
     mask_kind: str = "causal",  # 'causal' | 'window' | 'full'
     window: int = 0,
     attn_softcap: float = 0.0,
+    norm_eps: float = 1e-6,
 ) -> jax.Array:
     """Self-attention over a full sequence (train / prefill)."""
-    q, k, v = _project_qkv(p, x, x, qk_norm=qk_norm)
+    q, k, v = _project_qkv(p, x, x, qk_norm=qk_norm, norm_eps=norm_eps)
     q = rope_lib.apply_positional(q, positions, rope_type, rope_theta, mrope_sections)
     k = rope_lib.apply_positional(k, positions, rope_type, rope_theta, mrope_sections)
     q = shd(q, "batch", "seq", "heads_act", "head_dim")
@@ -211,8 +212,9 @@ def apply_attention_decode(
     window: int = 0,
     attn_softcap: float = 0.0,
     long_context: bool = False,
+    norm_eps: float = 1e-6,
 ):
-    q, k, v = _project_qkv(p, x, x, qk_norm=qk_norm)
+    q, k, v = _project_qkv(p, x, x, qk_norm=qk_norm, norm_eps=norm_eps)
     q = rope_lib.apply_positional(q, positions, rope_type, rope_theta, mrope_sections)
     k = rope_lib.apply_positional(k, positions, rope_type, rope_theta, mrope_sections)
     ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), index, axis=1)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_lib
+from repro.models import conv as conv_lib
 from repro.models import mamba as mamba_lib
 from repro.models import moe as moe_lib
 from repro.models import xlstm as xlstm_lib
@@ -104,6 +105,9 @@ def init_block(key, cfg: ModelConfig, kind: str, moe_here: bool) -> dict:
             k1, cfg.d_model, expand=cfg.mamba_expand, d_state=cfg.mamba_d_state,
             d_conv=cfg.mamba_d_conv, num_layers=cfg.num_layers, dtype=dt,
         )
+    elif kind == "conv":
+        p["conv"] = conv_lib.init_conv(k1, cfg.d_model, cfg.conv_kernel,
+                                       cfg.num_layers, dt)
     elif kind == "mlstm":
         p["mlstm"] = xlstm_lib.init_mlstm(k1, cfg.d_model, cfg.num_heads, cfg.num_layers, dt)
         return p  # self-contained block
@@ -115,7 +119,8 @@ def init_block(key, cfg: ModelConfig, kind: str, moe_here: bool) -> dict:
     p["norm2"] = init_norm(cfg.d_model, cfg.norm_type, dt)
     if moe_here:
         p["moe"] = moe_lib.init_moe(
-            k2, cfg.d_model, cfg.expert_d_ff, cfg.num_experts, cfg.num_layers, dt
+            k2, cfg.d_model, cfg.expert_d_ff, cfg.num_experts, cfg.num_layers, dt,
+            router_experts=cfg.router_experts, router_bias=cfg.router_bias,
         )
     else:
         p["mlp"] = init_mlp(k2, cfg.d_model, cfg.d_ff, cfg.num_layers, dt)
@@ -131,37 +136,80 @@ def _mixer_kwargs(cfg: ModelConfig, kind: str) -> dict:
         mask_kind="window" if kind == "attn_local" else "causal",
         window=cfg.sliding_window if kind == "attn_local" else 0,
         attn_softcap=cfg.attn_softcap,
+        norm_eps=cfg.rms_norm_eps,
     )
 
 
+def _dropless(cfg: ModelConfig) -> bool:
+    return cfg.moe and cfg.moe_impl == "dropless"
+
+
+def _routed_share(cfg: ModelConfig) -> bool:
+    """The router is ``moe.route``'s: sigmoid or biased scores, or more
+    outputs than the experts held here."""
+    return (cfg.router_score != "softmax" or cfg.router_bias
+            or cfg.num_router_experts != cfg.num_experts)
+
+
+def _no_rows(cfg: ModelConfig) -> dict:
+    """Routed-row counts a block adds to the step's metrics: the dropless
+    layer's rows computed here and its largest held expert's rows, summed
+    over layers (nothing for other configs)."""
+    if not _dropless(cfg):
+        return {}
+    return {"held": jnp.zeros((), jnp.int32), "max": jnp.zeros((), jnp.int32)}
+
+
+def _apply_ffn(p: dict, h, cfg: ModelConfig, moe_here: bool):
+    """(y, aux, rows) of the block's MLP or MoE."""
+    if not moe_here:
+        return apply_mlp(p["mlp"], h, cfg.act), jnp.zeros((), jnp.float32), _no_rows(cfg)
+    routing = dict(top_k=cfg.top_k, score=cfg.router_score,
+                   norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
+                   first=cfg.first_expert)
+    if _dropless(cfg):
+        return moe_lib.apply_moe_dropless(p["moe"], h, act=cfg.act, **routing)
+    if _routed_share(cfg):
+        if cfg.moe_impl != "capacity":
+            raise ValueError(f"{cfg.name}: a sigmoid or partly held router "
+                             f"runs 'dropless' or 'capacity'")
+        gates = moe_lib.share_gates(p["moe"], h, **routing)
+        y, aux = moe_lib.apply_moe_capacity(p["moe"], h, top_k=cfg.top_k,
+                                            act=cfg.act, gates=gates,
+                                            num_router=cfg.num_router_experts)
+        return y, aux, _no_rows(cfg)
+    y, aux = moe_lib.apply_moe(p["moe"], h, top_k=cfg.top_k, act=cfg.act,
+                               impl=cfg.moe_impl or None)
+    return y, aux, _no_rows(cfg)
+
+
 def apply_block(p: dict, x, kind: str, cfg: ModelConfig, positions, moe_here: bool):
-    """Full-sequence block. Returns (x, aux_loss)."""
+    """Full-sequence block. Returns (x, aux_loss, rows)."""
     aux = jnp.zeros((), jnp.float32)
-    h = apply_norm(p["norm1"], x, cfg.norm_type)
+    h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.rms_norm_eps)
     if kind.startswith("attn"):
         mix = attn_lib.apply_attention(p["attn"], h, positions=positions, **_mixer_kwargs(cfg, kind))
     elif kind == "mamba":
         mix = mamba_lib.apply_mamba(p["mamba"], h, d_state=cfg.mamba_d_state)
+    elif kind == "conv":
+        mix = conv_lib.apply_conv(p["conv"], h)
     elif kind == "mlstm":
-        return x + xlstm_lib.apply_mlstm(p["mlstm"], h, cfg.num_heads), aux
+        return x + xlstm_lib.apply_mlstm(p["mlstm"], h, cfg.num_heads), aux, _no_rows(cfg)
     elif kind == "slstm":
-        return x + xlstm_lib.apply_slstm(p["slstm"], h, cfg.num_heads), aux
+        return x + xlstm_lib.apply_slstm(p["slstm"], h, cfg.num_heads), aux, _no_rows(cfg)
     else:
         raise ValueError(kind)
     x = x + mix
-    h = apply_norm(p["norm2"], x, cfg.norm_type)
-    if moe_here:
-        y, aux = moe_lib.apply_moe(p["moe"], h, top_k=cfg.top_k, act=cfg.act)
-    else:
-        y = apply_mlp(p["mlp"], h, cfg.act)
+    h = apply_norm(p["norm2"], x, cfg.norm_type, cfg.rms_norm_eps)
+    y, aux, rows = _apply_ffn(p, h, cfg, moe_here)
     x = x + y
     x = shd(x, "batch", "seq", "embed_act")
-    return x, aux
+    return x, aux, rows
 
 
 def apply_block_decode(p, x, kind, cfg, positions, index, cache, moe_here, long_context):
     """One-token block step. Returns (x, new_cache)."""
-    h = apply_norm(p["norm1"], x, cfg.norm_type)
+    h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.rms_norm_eps)
     if kind.startswith("attn"):
         kw = _mixer_kwargs(cfg, kind)
         mix, cache = attn_lib.apply_attention_decode(
@@ -170,6 +218,7 @@ def apply_block_decode(p, x, kind, cfg, positions, index, cache, moe_here, long_
             mrope_sections=kw["mrope_sections"], qk_norm=kw["qk_norm"],
             mask_kind=kw["mask_kind"], window=kw["window"],
             attn_softcap=kw["attn_softcap"], long_context=long_context,
+            norm_eps=kw["norm_eps"],
         )
     elif kind == "mamba":
         mix, cache = mamba_lib.apply_mamba_decode(p["mamba"], h, cache, d_state=cfg.mamba_d_state)
@@ -182,11 +231,8 @@ def apply_block_decode(p, x, kind, cfg, positions, index, cache, moe_here, long_
     else:
         raise ValueError(kind)
     x = x + mix
-    h = apply_norm(p["norm2"], x, cfg.norm_type)
-    if moe_here:
-        y, _ = moe_lib.apply_moe(p["moe"], h, top_k=cfg.top_k, act=cfg.act)
-    else:
-        y = apply_mlp(p["mlp"], h, cfg.act)
+    h = apply_norm(p["norm2"], x, cfg.norm_type, cfg.rms_norm_eps)
+    y, _, _ = _apply_ffn(p, h, cfg, moe_here)
     return x + y, cache
 
 
@@ -242,6 +288,13 @@ def lm_forward(
     remat_policy: str = "full",
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns (logits (b, s, vocab), aux_loss)."""
+    logits, aux, _ = _lm_forward(params, batch, cfg, remat_policy)
+    return logits, aux
+
+
+def _lm_forward(params, batch, cfg: ModelConfig, remat_policy: str):
+    """(logits, aux_loss, rows): ``rows`` sums the blocks' routed-row
+    counts (empty unless the MoE is dropless)."""
     x = embed_inputs(params, cfg, batch)
     b, s = x.shape[0], x.shape[1]
     if "positions" in batch:
@@ -249,27 +302,41 @@ def lm_forward(
     else:
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
 
-    def group_fn(carry, gp):
-        x, aux = carry
-        for i, kind in enumerate(cfg.block_pattern):
-            x, a = apply_block(gp[f"b{i}"], x, kind, cfg, positions, _is_moe_pos(cfg, i))
-            aux = aux + a
-        return (x, aux), None
+    def block_fn(i, kind):
+        def fn(p, x):
+            return apply_block(p, x, kind, cfg, positions, _is_moe_pos(cfg, i))
+        # a group of several blocks keeps one block's intermediates at a
+        # time in the backward pass: remat goes per block
+        return _remat(fn, remat_policy) if len(cfg.block_pattern) > 1 else fn
 
-    body = _remat(group_fn, remat_policy)
-    (x, aux), _ = scan_or_unroll(body, (x, jnp.zeros((), jnp.float32)), params["groups"])
-    x = apply_norm(params["final_norm"], x, cfg.norm_type)
+    blocks = [block_fn(i, kind) for i, kind in enumerate(cfg.block_pattern)]
+
+    def group_fn(carry, gp):
+        x, aux, rows = carry
+        for i, fn in enumerate(blocks):
+            x, a, r = fn(gp[f"b{i}"], x)
+            aux = aux + a
+            rows = jax.tree.map(jnp.add, rows, r)
+        return (x, aux, rows), None
+
+    body = group_fn if len(cfg.block_pattern) > 1 else _remat(group_fn, remat_policy)
+    init = (x, jnp.zeros((), jnp.float32), _no_rows(cfg))
+    (x, aux, rows), _ = scan_or_unroll(body, init, params["groups"])
+    x = apply_norm(params["final_norm"], x, cfg.norm_type, cfg.rms_norm_eps)
     table = params["embed"]["table"].T if cfg.tie_embeddings else params["unembed"]["table"]
     logits = apply_unembed(table, x)
     logits = softcap(logits, cfg.logit_softcap)
-    return logits, aux
+    return logits, aux, rows
 
 
 def lm_loss(params, batch, cfg: ModelConfig, *, remat_policy: str = "full"):
-    logits, aux = lm_forward(params, batch, cfg, remat_policy=remat_policy)
+    logits, aux, rows = _lm_forward(params, batch, cfg, remat_policy)
     ce = cross_entropy(logits, batch["labels"])
     loss = ce + cfg.router_aux_coef * aux
-    return loss, {"ce": ce, "aux": aux}
+    metrics = {"ce": ce, "aux": aux}
+    if rows:
+        metrics.update(moe_rows_held=rows["held"], moe_rows_max=rows["max"])
+    return loss, metrics
 
 
 # ---------------------------------------------------------------------------
@@ -365,7 +432,7 @@ def lm_decode_step(
         return x, new_c
 
     x, new_cache = scan_or_unroll(group_fn, x, (params["groups"], cache))
-    x = apply_norm(params["final_norm"], x, cfg.norm_type)
+    x = apply_norm(params["final_norm"], x, cfg.norm_type, cfg.rms_norm_eps)
     table = params["embed"]["table"].T if cfg.tie_embeddings else params["unembed"]["table"]
     logits = apply_unembed(table, x)
     logits = softcap(logits, cfg.logit_softcap)

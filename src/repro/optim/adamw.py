@@ -31,7 +31,9 @@ def _is_float(x) -> bool:
 
 def init_opt_state(params) -> Dict[str, Any]:
     f32 = lambda x: jnp.zeros(x.shape, jnp.float32)
-    master = jax.tree.map(lambda x: x.astype(jnp.float32), params)
+    # a buffer of its own even where the params are float32 already, so
+    # that a step may be handed (donated) both
+    master = jax.tree.map(lambda x: jnp.array(x, jnp.float32, copy=True), params)
     return {
         "step": jnp.zeros((), jnp.int32),
         "master": master,

@@ -30,6 +30,10 @@ class TrainerConfig:
     log_every: int = 25
     seed: int = 0
     step_cfg: TrainStepConfig = field(default_factory=TrainStepConfig)
+    # hand the step's input params and optimizer state to its outputs (a
+    # state that fits only once on the device); the arrays passed in are
+    # then deleted
+    donate_state: bool = False
 
 
 class Trainer:
@@ -47,7 +51,8 @@ class Trainer:
         self.ckpt = ckpt
         self.cfg = cfg
         self.preempt_signal = preempt_signal or (lambda step: False)
-        self.train_step = jax.jit(make_train_step(model, cfg.step_cfg))
+        self.train_step = jax.jit(make_train_step(model, cfg.step_cfg),
+                                  donate_argnums=(0, 1) if cfg.donate_state else ())
         self.params = None
         self.opt_state = None
         self.step = 0
@@ -106,6 +111,11 @@ class Trainer:
                 row = {k: float(v) for k, v in metrics.items()}
                 row["step"] = self.step
                 self.history.append(row)
+                if "moe_rows_held" in row:
+                    # a dropless expert layer's routed rows, of the steps read
+                    telemetry.count("moe.rows_held", int(row["moe_rows_held"]))
+                    telemetry.count("moe.rows_max", int(row["moe_rows_max"]))
+                    telemetry.count("moe.steps_read")
             if self.step % self.cfg.save_every == 0:
                 self.save()
         if status == "done" and self.step >= self.cfg.total_steps:

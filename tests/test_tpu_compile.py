@@ -8,6 +8,7 @@ described inside a fixture, so only the test worker that runs this file
 loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -107,3 +108,40 @@ def test_int8_kernels_compile_at_ragged_row_count(one_chip, kernel):
     assert "tpu_custom_call" in hlo
     assert {"quantize": "%_quant_kernel.", "dequantize": "%_dequant_kernel."}[
         kernel] in hlo
+
+
+def test_expert_layer_and_conv_compile_at_lfm2_widths(one_chip):
+    """The dropless expert layer (grouped products over lfm2-moe-train's
+    worst-case 65,536-row buffer, 8 of 32 experts held) and the conv
+    block compile for the chip, forward and backward, and their named
+    scopes are in the ``op_name`` of the compiled operations, which is
+    what the trace's operations carry and what the benchmark's expert
+    readers select by."""
+    from repro.models import conv as conv_lib
+    from repro.models import moe as moe_lib
+
+    d, f, E, R = 2048, 1792, 8, 32
+    p = jax.eval_shape(lambda k: {
+        "moe": moe_lib.init_moe(k, d, f, E, 4, jnp.float32, router_experts=R,
+                                router_bias=True),
+        "conv": conv_lib.init_conv(k, d, 3, 4, jnp.float32)},
+        jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((8, 2048, d), jnp.float32)
+
+    def loss(p, x):
+        y = conv_lib.apply_conv(p["conv"], x)
+        y, _, _ = moe_lib.apply_moe_dropless(
+            p["moe"], y, top_k=4, act="silu", score="sigmoid")
+        return jnp.sum(y)
+
+    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    hlo = jax.jit(jax.grad(loss)).lower(
+        jax.tree.map(on_chip, p), on_chip(x)).compile().as_text()
+    assert "ragged-dot" in hlo  # grouped products, not dense ones
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in ("moe.route", "moe.experts", "moe.combine", "conv"):
+        # forward, and the backward's operations under ``transpose(...)``
+        for kind in ("jvp(", "transpose("):
+            assert any(kind in n and re.search(
+                r"(^|[/(])" + re.escape(scope) + r"($|[/)])", n)
+                for n in names), (scope, kind)
