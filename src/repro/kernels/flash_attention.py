@@ -1,9 +1,10 @@
-"""Pallas TPU flash attention, VMEM-tiled online softmax.
+"""Pallas TPU flash attention, VMEM-tiled online softmax, forward and
+backward.
 
 TPU-native adaptation (DESIGN.md §8): q tiles of BLOCK_Q=256 rows stream
 through VMEM while the kv reduction runs along the innermost grid axis;
 (m, l, acc) online-softmax carries live in VMEM scratch across kv steps.
-The kernel works head-major — ``(b, nh, s, hd)`` with ``(bq, hd)`` tiles,
+The kernels work head-major — ``(b, nh, s, hd)`` with ``(bq, hd)`` tiles,
 so a tile's last two dims are a sequence slice (a multiple of 8, or the
 whole sequence) and the whole head dim — and the public wrapper keeps
 the model's ``(b, s, nh, hd)`` layout.  Supports causal masking, sliding
@@ -11,11 +12,17 @@ windows (gemma2 local layers), GQA head grouping via BlockSpec index
 maps, and tanh soft-capping — fused, so the masked QK^T logits never
 round-trip to HBM.
 
-The backward pass is the oracle's (``kernels/ref.py``) through
-``jax.vjp``, attached with ``jax.custom_vjp``: ``jax.grad`` of a train
-step differentiates the same math the kernel computes forward.  It
-materialises the float32 scores, so where they would pass
-``SCORE_BYTES_MAX`` it runs over chunks of batch rows, one after another.
+The backward pass (FlashAttention-2 style, attached with
+``jax.custom_vjp``) is two more kernels fed by the forward's per-row
+log-sum-exp ``lse = m + log(l)``: ``flash_attention_bwd_dkv`` holds one
+kv tile and reduces over the query tiles of every query head of its
+group (GQA summed through the index maps), ``flash_attention_bwd_dq``
+holds one query tile and reduces over the kv tiles.  Both recompute the
+scores tile by tile, ``p = exp(s - lse)``, ``dS = p * (dO v^T - delta)``
+with ``delta = rowsum(dO * out)``, so no score, probability or
+score-gradient tensor larger than one tile reaches HBM.  Tiles that the
+mask empties are skipped: no compute, and their index maps repeat the
+previous block so no copy is issued.
 
 Validated against kernels/ref.py in interpret mode (CPU) by
 tests/test_kernels.py; selected automatically on TPU by kernels/ops.py.
@@ -29,14 +36,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import ref as ref_lib
-
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 NEG_INF = -2.0e38
-#: the largest float32 score tensor (rows x heads x queries x keys) the
-#: backward pass materialises at once
-SCORE_BYTES_MAX = 3 << 29  # 1.5 GiB
+LANES = 128
+# contract the last dims (a @ b.T) and the inner dims (a @ b)
+_NT = (((1,), (1,)), ((), ()))
+_NN = (((1,), (0,)), ((), ()))
+
+
+def _dot(a, b, dims):
+    """A tile product on the operands' own dtypes (the wider where they
+    differ), accumulated in float32 at default precision."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    return jax.lax.dot_general(a.astype(dt), b.astype(dt), dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _mask(s, qpos, kpos, mask_kind, window):
+    """The forward's mask on a tile of scores."""
+    if mask_kind == "full":
+        return s
+    ok = kpos <= qpos
+    if mask_kind == "window" and window > 0:
+        ok &= (qpos - kpos) < window
+    return jnp.where(ok, s, NEG_INF)
+
+
+def _column_to_row(col):
+    """An (n, 1) column as a (1, n) row, through a lane-aligned transpose."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], LANES)))[:1]
+
+
+def _row_to_column(row):
+    """A (1, n) row as an (n, 1) column, through a lane-aligned transpose."""
+    return jnp.transpose(jnp.broadcast_to(row, (LANES, row.shape[1])))[:, :1]
 
 
 def _attn_kernel(
@@ -44,10 +78,8 @@ def _attn_kernel(
     k_ref,  # (bk, hd)
     v_ref,  # (bk, hd)
     o_ref,  # (bq, hd)
-    m_scr,  # (bq, 1) f32  running max
-    l_scr,  # (bq, 1) f32  running denom
-    acc_scr,  # (bq, hd) f32  running numerator
-    *,
+    *refs,  # [lse (1, bq) f32,] then the scratch below
+    with_lse: bool,
     mask_kind: str,
     window: int,
     attn_softcap: float,
@@ -55,6 +87,10 @@ def _attn_kernel(
     block_k: int,
     n_k_blocks: int,
 ):
+    lse_ref = refs[0] if with_lse else None
+    m_scr, l_scr, acc_scr = refs[1:] if with_lse else refs
+    # m_scr (bq, 1) f32 running max, l_scr (bq, 1) f32 running denom,
+    # acc_scr (bq, hd) f32 running numerator
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -77,11 +113,7 @@ def _attn_kernel(
 
     qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    if mask_kind != "full":
-        ok = kpos <= qpos
-        if mask_kind == "window" and window > 0:
-            ok &= (qpos - kpos) < window
-        s = jnp.where(ok, s, NEG_INF)
+    s = _mask(s, qpos, kpos, mask_kind, window)
 
     m_prev = m_scr[...]
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -99,12 +131,15 @@ def _attn_kernel(
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
         o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        if with_lse:
+            lse_ref[...] = _column_to_row(m_scr[...] + jnp.log(denom))
 
 
 def _forward(q, k, v, mask_kind, window, attn_softcap, block_q, block_k,
-             interpret):
+             interpret, with_lse):
     """The kernel on head-major operands: q (b, nh, s, hd), k/v
-    (b, nkv, t, hd) -> (b, nh, s, hd)."""
+    (b, nkv, t, hd) -> (b, nh, s, hd), and with ``with_lse`` also the
+    float32 log-sum-exp of every query row as (b, nh, 1, s)."""
     b, nh, s, hd = q.shape
     nkv, t = k.shape[1], k.shape[2]
     group = nh // nkv
@@ -115,7 +150,7 @@ def _forward(q, k, v, mask_kind, window, attn_softcap, block_q, block_k,
     n_k = t // block_k
 
     kernel = functools.partial(
-        _attn_kernel,
+        _attn_kernel, with_lse=with_lse,
         mask_kind=mask_kind, window=window, attn_softcap=attn_softcap,
         block_q=block_q, block_k=block_k, n_k_blocks=n_k,
     )
@@ -124,12 +159,19 @@ def _forward(q, k, v, mask_kind, window, attn_softcap, block_q, block_k,
                           lambda bi, hi, qi, ki: (bi, hi, qi, 0))
     kv_spec = pl.BlockSpec((None, None, block_k, hd),
                            lambda bi, hi, qi, ki: (bi, hi // group, ki, 0))
+    out_specs = q_spec
+    out_shape = jax.ShapeDtypeStruct((b, nh, s, hd), q.dtype)
+    if with_lse:
+        out_specs = [q_spec, pl.BlockSpec(
+            (None, None, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, 0, qi))]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct((b, nh, 1, s), jnp.float32)]
     return pl.pallas_call(
         kernel,
         grid=(b, nh, n_q, n_k),
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nh, s, hd), q.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             # (bq, 1) m, (bq, 1) l, (bq, hd) acc — f32 online-softmax carries
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -143,60 +185,218 @@ def _forward(q, k, v, mask_kind, window, attn_softcap, block_q, block_k,
     )(q, k, v)
 
 
+def _q_blocks(ki, mask_kind, window, block_q, block_k, n_q):
+    """The first and last query tile that kv tile ``ki`` is visible
+    from (first > last when none is)."""
+    if mask_kind == "full":
+        return 0, n_q - 1
+    last = n_q - 1
+    if mask_kind == "window" and window > 0:
+        last = jnp.minimum(last, (ki * block_k + block_k + window - 2) // block_q)
+    return ki * block_k // block_q, last
+
+
+def _k_blocks(qi, mask_kind, window, block_q, block_k, n_k):
+    """The first and last kv tile that query tile ``qi`` sees."""
+    if mask_kind == "full":
+        return 0, n_k - 1
+    first = 0
+    if mask_kind == "window" and window > 0:
+        first = jnp.maximum(qi * block_q - window + 1, 0) // block_k
+    return first, jnp.minimum(n_k - 1, (qi * block_q + block_q - 1) // block_k)
+
+
+def _tile_grads(x, y, dx, dy, lse, delta, qpos, kpos, mask_kind, window,
+                attn_softcap):
+    """p and dS, the gradient of the (scaled) scores, on one tile whose
+    scores are ``x @ y.T`` and whose ``dP`` is ``dx @ dy.T``: the query
+    side is (x, dx) = (q, dO) with ``lse`` and ``delta`` as columns, or
+    the key side is (x, dx) = (k, v), the tile transposed, with them as
+    rows."""
+    s = _dot(x, y, _NT) * (x.shape[-1] ** -0.5)
+    if attn_softcap:
+        th = jnp.tanh(s / attn_softcap)
+        s = attn_softcap * th
+    p = jnp.exp(_mask(s, qpos, kpos, mask_kind, window) - lse)
+    ds = p * (_dot(dx, dy, _NT) - delta)
+    if attn_softcap:
+        ds = ds * (1.0 - th * th)
+    return p, ds
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dk_scr, dv_scr, *, mask_kind, window,
+                attn_softcap, block_q, block_k, n_q, n_steps):
+    ki = pl.program_id(2)
+    j = pl.program_id(3)  # (query head of the group, query tile)
+    qi = j % n_q
+
+    @pl.when(j == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    first, last = _q_blocks(ki, mask_kind, window, block_q, block_k, n_q)
+
+    @pl.when((qi >= first) & (qi <= last))
+    def _accumulate():
+        q, do = q_ref[...], do_ref[...]
+        shape = (block_k, block_q)  # the tile transposed: kv rows
+        kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        pT, dsT = _tile_grads(k_ref[...], q, v_ref[...], do, lse_ref[...],
+                              delta_ref[...], qpos, kpos, mask_kind, window,
+                              attn_softcap)
+        dv_scr[...] += _dot(pT, do, _NN)
+        dk_scr[...] += _dot(dsT, q, _NN)
+
+    @pl.when(j == n_steps - 1)
+    def _finalize():
+        scale = q_ref.shape[-1] ** -0.5
+        dk_ref[...] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+               dq_scr, lse_scr, delta_scr, *, mask_kind, window,
+               attn_softcap, block_q, block_k, n_k):
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+        lse_scr[...] = _row_to_column(lse_ref[...])
+        delta_scr[...] = _row_to_column(delta_ref[...])
+
+    first, last = _k_blocks(qi, mask_kind, window, block_q, block_k, n_k)
+
+    @pl.when((ki >= first) & (ki <= last))
+    def _accumulate():
+        k = k_ref[...]
+        shape = (block_q, block_k)
+        qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        _, ds = _tile_grads(q_ref[...], k, do_ref[...], v_ref[...],
+                            lse_scr[...], delta_scr[...], qpos, kpos,
+                            mask_kind, window, attn_softcap)
+        dq_scr[...] += _dot(ds, k, _NN)
+
+    @pl.when(ki == n_k - 1)
+    def _finalize():
+        scale = q_ref.shape[-1] ** -0.5
+        dq_ref[...] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+
+
+def _backward(q, k, v, do, lse, delta, mask_kind, window, attn_softcap,
+              block_q, block_k, interpret):
+    """dq, dk, dv on head-major operands, from the forward's ``lse`` and
+    ``delta = rowsum(dO * out)``, both (b, nh, 1, s) float32."""
+    b, nh, s, hd = q.shape
+    nkv, t = k.shape[1], k.shape[2]
+    group = nh // nkv
+    block_q = min(block_q, s)
+    block_k = min(block_k, t)
+    n_q, n_k = s // block_q, t // block_k
+    static = dict(mask_kind=mask_kind, window=window,
+                  attn_softcap=attn_softcap, block_q=block_q, block_k=block_k)
+
+    # dk, dv: grid (batch, kv head, kv tile, (query head of the group,
+    # query tile)); a skipped query tile maps to the nearest visible one,
+    # so its step repeats a block already there
+    def q_tile(bi, hk, ki, j):
+        first, last = _q_blocks(ki, mask_kind, window, block_q, block_k, n_q)
+        return bi, hk * group + j // n_q, jnp.minimum(
+            jnp.maximum(j % n_q, first), last)
+
+    def q_map(bi, hk, ki, j):
+        return (*q_tile(bi, hk, ki, j), 0)
+
+    def row_map(bi, hk, ki, j):
+        bi, hi, qi = q_tile(bi, hk, ki, j)
+        return bi, hi, 0, qi
+
+    q_spec = pl.BlockSpec((None, None, block_q, hd), q_map)
+    row_spec = pl.BlockSpec((None, None, 1, block_q), row_map)
+    kv_spec = pl.BlockSpec((None, None, block_k, hd),
+                           lambda bi, hk, ki, j: (bi, hk, ki, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, n_q=n_q, n_steps=group * n_q,
+                          **static),
+        grid=(b, nkv, n_k, group * n_q),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
+                        pltpu.VMEM((block_k, hd), jnp.float32)],
+        interpret=interpret,
+        name="flash_attention_bwd_dkv",
+    )(q, k, v, do, lse, delta)
+
+    # dq: grid (batch, head, query tile, kv tile); a skipped kv tile maps
+    # to the nearest visible one
+    def kv_map(bi, hi, qi, ki):
+        first, last = _k_blocks(qi, mask_kind, window, block_q, block_k, n_k)
+        return bi, hi // group, jnp.minimum(jnp.maximum(ki, first), last), 0
+
+    q_spec = pl.BlockSpec((None, None, block_q, hd),
+                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    row_spec = pl.BlockSpec((None, None, 1, block_q),
+                            lambda bi, hi, qi, ki: (bi, hi, 0, qi))
+    kv_spec = pl.BlockSpec((None, None, block_k, hd), kv_map)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, n_k=n_k, **static),
+        grid=(b, nh, n_q, n_k),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32)],
+        interpret=interpret,
+        name="flash_attention_bwd_dq",
+    )(q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+def _head_major(x):
+    return x.transpose(0, 2, 1, 3)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _attention(q, k, v, mask_kind, window, attn_softcap, block_q, block_k,
                interpret):
     """(b, s, nh, hd) in and out; the kernel runs head-major."""
-    out = _forward(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                   v.transpose(0, 2, 1, 3), mask_kind, window, attn_softcap,
-                   block_q, block_k, interpret)
-    return out.transpose(0, 2, 1, 3)
+    out = _forward(_head_major(q), _head_major(k), _head_major(v), mask_kind,
+                   window, attn_softcap, block_q, block_k, interpret,
+                   with_lse=False)
+    return _head_major(out)
 
 
 def _attention_fwd(q, k, v, mask_kind, window, attn_softcap, block_q,
                    block_k, interpret):
-    out = _attention(q, k, v, mask_kind, window, attn_softcap, block_q,
-                     block_k, interpret)
-    return out, (q, k, v)
-
-
-def bwd_rows(b: int, nh: int, s: int, t: int) -> int:
-    """Batch rows per chunk of the backward pass: the most rows, dividing
-    ``b``, whose scores fit in ``SCORE_BYTES_MAX`` (at least one)."""
-    per_row = 4 * nh * s * t
-    return max(c for c in range(1, b + 1)
-               if b % c == 0 and (c == 1 or c * per_row <= SCORE_BYTES_MAX))
+    q, k, v = _head_major(q), _head_major(k), _head_major(v)
+    out, lse = _forward(q, k, v, mask_kind, window, attn_softcap, block_q,
+                        block_k, interpret, with_lse=True)
+    return _head_major(out), (q, k, v, out, lse)
 
 
 def _attention_bwd(mask_kind, window, attn_softcap, block_q, block_k,
                    interpret, res, g):
-    ref = functools.partial(ref_lib.flash_attention_ref, mask_kind=mask_kind,
-                            window=window, attn_softcap=attn_softcap)
-
-    def vjp_of(q, k, v, g):
-        _, vjp = jax.vjp(ref, q, k, v)
-        return vjp(g)
-
-    q, k, v = res
-    b = q.shape[0]
-    rows = bwd_rows(b, q.shape[2], q.shape[1], k.shape[1])
-    if rows == b:
-        return vjp_of(q, k, v, g)
-    chunks = [x.reshape(b // rows, rows, *x.shape[1:]) for x in (q, k, v, g)]
-    grads = jax.lax.map(lambda a: vjp_of(*a), tuple(chunks))
-    return tuple(x.reshape(b, *x.shape[2:]) for x in grads)
+    q, k, v, out, lse = res
+    do = _head_major(g)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)[:, :, None, :]
+    grads = _backward(q, k, v, do, lse, delta, mask_kind, window,
+                      attn_softcap, block_q, block_k, interpret)
+    return tuple(_head_major(x) for x in grads)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "mask_kind", "window", "attn_softcap", "block_q", "block_k", "interpret",
-    ),
-)
-def flash_attention_pallas(
+def _flash_attention(
     q: jax.Array,  # (b, s, nh, hd)
     k: jax.Array,  # (b, t, nkv, hd)
     v: jax.Array,
@@ -210,3 +410,13 @@ def flash_attention_pallas(
 ) -> jax.Array:
     return _attention(q, k, v, mask_kind, window, attn_softcap, block_q,
                       block_k, interpret)
+
+
+# XLA names the kernels' results after the jitted function, and the
+# trace writes those names into every operation that reads them: so only
+# the forward kernel itself may carry "flash_attention_pallas", which the
+# benchmark's flash_attn_roofline matches
+flash_attention_pallas = jax.jit(
+    _flash_attention,
+    static_argnames=("mask_kind", "window", "attn_softcap", "block_q",
+                     "block_k", "interpret"))

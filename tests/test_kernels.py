@@ -63,28 +63,87 @@ def test_flash_attention_block_shapes():
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6, rtol=2e-6)
 
 
-@pytest.mark.parametrize("mask,win,cap,nkv", [
-    ("causal", 0, 0.0, 4),
-    ("window", 64, 30.0, 2),
-    ("full", 0, 0.0, 1),
-])
-def test_flash_attention_grad_matches_oracle(mask, win, cap, nkv):
-    """jax.grad through the kernel (its custom_vjp) equals the oracle's
-    gradient, for q, k and v."""
-    q, k, v = _qkv(jax.random.PRNGKey(4), 2, 128, 128, 4, nkv, 64, jnp.float32)
+GRAD_SWEEP = [
+    # (s, t, nh, nkv, hd, mask, window, softcap, block_q, block_k, dtype, tol)
+    pytest.param(128, 128, 4, 4, 64, "causal", 0, 0.0, 64, 64, jnp.float32,
+                 1e-5, id="causal-0-0.0-4"),
+    pytest.param(128, 128, 4, 2, 64, "window", 64, 30.0, 64, 64, jnp.float32,
+                 1e-5, id="window-64-30.0-2"),
+    pytest.param(128, 128, 4, 1, 64, "full", 0, 0.0, 64, 64, jnp.float32,
+                 1e-5, id="full-0-0.0-1"),
+    # several query and kv tiles, not the same size, groups of 3 and 4
+    pytest.param(256, 256, 6, 2, 64, "causal", 0, 0.0, 64, 128, jnp.float32,
+                 1e-5, id="causal-group3-bq64-bk128"),
+    pytest.param(256, 256, 6, 2, 64, "causal", 0, 50.0, 128, 32,
+                 jnp.float32, 1e-5, id="causal-cap50-group3-bq128-bk32"),
+    pytest.param(256, 256, 8, 2, 32, "window", 40, 0.0, 32, 64, jnp.float32,
+                 1e-5, id="window40-group4-bq32-bk64"),
+    pytest.param(128, 128, 3, 3, 64, "window", 4096, 0.0, 32, 64,
+                 jnp.float32, 1e-5, id="window-beyond-seq-group1"),
+    pytest.param(128, 128, 6, 2, 32, "full", 0, 30.0, 64, 32, jnp.float32,
+                 1e-5, id="full-cap30-group3-bq64-bk32"),
+    # t unequal to s
+    pytest.param(64, 128, 8, 2, 32, "causal", 0, 0.0, 32, 32, jnp.float32,
+                 1e-5, id="causal-s64-t128-group4"),
+    pytest.param(128, 64, 6, 2, 32, "full", 0, 0.0, 32, 32, jnp.float32,
+                 1e-5, id="full-s128-t64-group3"),
+    pytest.param(256, 256, 6, 2, 64, "causal", 0, 0.0, 64, 128,
+                 jnp.bfloat16, 2e-2, id="bf16-causal-group3"),
+    pytest.param(128, 128, 8, 2, 32, "window", 48, 30.0, 32, 64,
+                 jnp.bfloat16, 2e-2, id="bf16-window48-cap30-group4"),
+]
+
+
+@pytest.mark.parametrize("s,t,nh,nkv,hd,mask,win,cap,bq,bk,dtype,tol",
+                         GRAD_SWEEP)
+def test_flash_attention_grad_matches_oracle(s, t, nh, nkv, hd, mask, win,
+                                             cap, bq, bk, dtype, tol):
+    """jax.grad through the kernel (its custom_vjp: the Pallas dk/dv and
+    dq kernels) equals jax.grad of the oracle, for q, k and v."""
+    q, k, v = _qkv(jax.random.PRNGKey(4), 2, s, t, nh, nkv, hd, dtype)
     w = jax.random.normal(jax.random.PRNGKey(5), q.shape, jnp.float32)
 
     def loss(fn):
-        return lambda q, k, v: jnp.sum(
-            fn(q, k, v, mask_kind=mask, window=win, attn_softcap=cap) * w)
+        return lambda q, k, v: jnp.sum(fn(
+            q, k, v, mask_kind=mask, window=win, attn_softcap=cap
+        ).astype(jnp.float32) * w)
 
-    pallas = functools.partial(flash_attention_pallas, block_q=64,
-                               block_k=64, interpret=True)
+    pallas = functools.partial(flash_attention_pallas, block_q=bq,
+                               block_k=bk, interpret=True)
     got = jax.grad(loss(pallas), (0, 1, 2))(q, k, v)
     want = jax.grad(loss(ref.flash_attention_ref), (0, 1, 2))(q, k, v)
     for g, r in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
-                                   atol=1e-5, rtol=1e-5)
+        assert g.dtype == r.dtype
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(r, np.float32),
+                                   atol=tol, rtol=tol)
+
+
+def _kernel_calls(jaxpr):
+    """(name, number of outputs) of every Pallas call in a jaxpr, nested
+    ones included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["name"], len(eqn.outvars)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_kernel_calls(sub))
+    return out
+
+
+def test_primal_call_runs_the_single_output_forward():
+    """Without a gradient the forward kernel writes the output alone; the
+    log-sum-exp output and the backward kernels appear only under grad."""
+    q, k, v = _qkv(jax.random.PRNGKey(6), 1, 128, 128, 4, 2, 32, jnp.float32)
+    fwd = functools.partial(flash_attention_pallas, block_q=64, block_k=64,
+                            interpret=True)
+    primal = jax.make_jaxpr(fwd)(q, k, v)
+    assert _kernel_calls(primal.jaxpr) == [("flash_attention_pallas", 1)]
+    grad = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(fwd(q, k, v)),
+                                   (0, 1, 2)))(q, k, v)
+    assert sorted(_kernel_calls(grad.jaxpr)) == [
+        ("flash_attention_bwd_dkv", 2), ("flash_attention_bwd_dq", 1),
+        ("flash_attention_pallas", 2)]
 
 
 def test_ops_dispatch_ref_on_cpu():

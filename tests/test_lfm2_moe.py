@@ -1,7 +1,8 @@
 """LFM2-MoE on the CPU at small widths: the program (conv blocks, the
-dropless expert-share layer, per-block remat, the chunked attention
-backward) against the plain reference ``bench/configs/lfm2_moe_ref.py``
-on seeded random weights, and the published model's size."""
+dropless expert-share layer, per-block remat, the attention backward at
+lfm2's grouping) against the plain reference
+``bench/configs/lfm2_moe_ref.py`` on seeded random weights, and the
+published model's size."""
 import functools
 import importlib
 import importlib.util
@@ -15,6 +16,7 @@ import pytest
 
 from repro.configs import get_config, param_count
 from repro.configs.base import ModelConfig, active_param_count
+from repro.kernels import ref as kernel_ref
 from repro.models import build_model
 from repro.models import conv as conv_lib
 from repro.models import moe as moe_lib
@@ -151,32 +153,23 @@ def conv_is_causal():
     np.testing.assert_allclose(got, [0, 100, 210, 321, 432])
 
 
-def chunked_attention_backward_matches():
-    shape_q, shape_kv = (4, 64, 4, 16), (4, 64, 2, 16)
+def kernel_attention_backward_matches_at_lfm2_grouping():
+    """The Pallas backward at lfm2's grouping, 4 query heads a KV head,
+    over several query and KV tiles, against jax.grad of the oracle."""
+    shape_q, shape_kv = (2, 128, 8, 16), (2, 128, 2, 16)
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
     q, k, v = (jax.random.normal(ks[0], shape_q), jax.random.normal(ks[1], shape_kv),
                jax.random.normal(ks[2], shape_kv))
     g = jax.random.normal(ks[3], shape_q)
 
-    def grads():
-        def f(q, k, v):
-            return jnp.sum(fa.flash_attention_pallas(q, k, v, interpret=True) * g)
-        return jax.grad(f, (0, 1, 2))(q, k, v)
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * g), (0, 1, 2))(q, k, v)
 
-    whole = grads()
-    limit = fa.SCORE_BYTES_MAX
-    fa.SCORE_BYTES_MAX = 4 * 4 * 64 * 64  # one row's scores a chunk
-    try:
-        assert fa.bwd_rows(4, 4, 64, 64) == 1
-        jax.clear_caches()
-        chunked = grads()
-    finally:
-        fa.SCORE_BYTES_MAX = limit
-        jax.clear_caches()
-    for a, b in zip(whole, chunked):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
-    assert fa.bwd_rows(8, 9, 2048, 2048) == 8  # smollm2-train: one chunk
-    assert fa.bwd_rows(8, 32, 2048, 2048) == 2  # lfm2-moe-train: four
+    got = grads(functools.partial(fa.flash_attention_pallas, block_q=32,
+                                  block_k=32, interpret=True))
+    want = grads(kernel_ref.flash_attention_ref)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
 
 def per_block_remat_gives_the_same_gradient():
@@ -204,7 +197,8 @@ def registry_entry_has_the_published_size():
 CASES = {f.__name__: f for f in (
     loss_and_grad_match_reference, shares_add_up_to_the_uncut_layer,
     one_expert_batch_matches_reference, conv_is_causal,
-    chunked_attention_backward_matches, per_block_remat_gives_the_same_gradient,
+    kernel_attention_backward_matches_at_lfm2_grouping,
+    per_block_remat_gives_the_same_gradient,
     registry_entry_has_the_published_size)}
 
 
